@@ -20,6 +20,7 @@ from benchmarks import (bench_driver, bench_kernels,  # noqa: E402
                         bench_schedule, fig3_homogenize, roofline,
                         table2_noniid, table3_topology, table4_public,
                         table6_comm, table7_scale)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 SECTIONS = {
     "table2": lambda: table2_noniid.run(),
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated section names")
     args = ap.parse_args()
+    enable_compile_cache()
     names = (args.only.split(",") if args.only else list(SECTIONS))
     print("name,us_per_call,derived")
     failures = []

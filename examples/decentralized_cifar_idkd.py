@@ -24,6 +24,7 @@ from repro.configs.resnet20_cifar import SMALL_CONFIG
 from repro.core.idkd import skew_metric
 from repro.core.simulator import DecentralizedSimulator
 from repro.data.synthetic import make_classification_data, make_public_data
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -42,6 +43,7 @@ def main():
                     help="write the QG-IDKD run's telemetry (run.jsonl + "
                          "trace.json, DESIGN.md §11) under DIR")
     args = ap.parse_args()
+    enable_compile_cache()
 
     data = make_classification_data(image_size=8, n_train=1024, n_val=256,
                                     n_test=512, noise=2.2, seed=0)

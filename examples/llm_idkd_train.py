@@ -8,6 +8,7 @@ import argparse
 
 from repro.configs import get_config
 from repro.configs.base import IDKDConfig, TrainConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import run_training
 
 
@@ -18,6 +19,7 @@ def main():
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--nodes", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced()
     tcfg = TrainConfig(num_nodes=args.nodes, steps=args.steps, lr=0.1,
                        alpha=0.1, batch_size=8,

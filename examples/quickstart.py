@@ -14,9 +14,11 @@ from repro.configs.resnet20_cifar import SMALL_CONFIG
 from repro.core.idkd import skew_metric
 from repro.core.simulator import DecentralizedSimulator
 from repro.data.synthetic import make_classification_data, make_public_data
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # 1. synthetic CIFAR-like data + an unlabeled public set
     data = make_classification_data(image_size=8, n_train=1024, n_test=512,
                                     noise=1.6, seed=0)

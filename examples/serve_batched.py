@@ -6,14 +6,17 @@ with the KV-cache/SSM-state decode path (the one dryrun.py proves at
 """
 import argparse
 
-from repro.launch.serve import main as serve_main
 import sys
+
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import main as serve_main
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     args, rest = ap.parse_known_args()
+    enable_compile_cache()
     sys.argv = ["serve", "--arch", args.arch, "--requests", "4",
                 "--slots", "2", "--prompt-len", "6", "--gen-len", "8"] + rest
     serve_main()

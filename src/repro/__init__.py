@@ -1,16 +1,1 @@
-"""JAX/Pallas reproduction of IDKD decentralized learning.
-
-One piece of process-wide configuration lives here: the partitionable
-threefry PRNG. The legacy lowering (``jax_threefry_partitionable=False``,
-still the default on this JAX version) lets XLA's SPMD partitioner
-produce *different random values for the same key* depending on how the
-surrounding computation is sharded — a sampler traced into the jitted
-scan runner draws different batches on a ``(node=4,)`` mesh than on a
-``(node=4, model=2)`` one, silently breaking trajectory equivalence
-across mesh shapes. The partitionable implementation is
-sharding-invariant (and the upstream default going forward); the 2-D
-federation-mesh equivalence tests rely on it (DESIGN.md §10).
-"""
-import jax
-
-jax.config.update("jax_threefry_partitionable", True)
+"""JAX/Pallas reproduction of IDKD decentralized learning."""

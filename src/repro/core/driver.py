@@ -330,7 +330,6 @@ def make_shard_step(model, algo, loss_adapter, *, mesh, topology,
     (wire fault injection has no shard path — ``validate_shard_schedule``
     rejects drop/corrupt faults — so ``wire_invalid`` stays zero here).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import mixing
@@ -447,10 +446,10 @@ def make_shard_step(model, algo, loss_adapter, *, mesh, topology,
                 if carry is not None:
                     extra_specs += (node_stacked_specs(carry, n, axis),)
                     extra_args += (carry,)
-            sharded = shard_map(comm_body, mesh=mesh,
-                                in_specs=base_in + extra_specs,
-                                out_specs=base_out + extra_specs,
-                                check_rep=False)
+            sharded = jax.shard_map(comm_body, mesh=mesh,
+                                    in_specs=base_in + extra_specs,
+                                    out_specs=base_out + extra_specs,
+                                    check_vma=False)
             return sharded(params, opt_state, batch, lr, comm, *extra_args)
 
         comm_step.comm = True
@@ -502,10 +501,10 @@ def make_shard_step(model, algo, loss_adapter, *, mesh, topology,
             if carry is not None:
                 extra_specs += (node_stacked_specs(carry, n, axis),)
                 extra_args += (carry,)
-        sharded = shard_map(body, mesh=mesh,
-                            in_specs=base_in + extra_specs,
-                            out_specs=base_out + extra_specs,
-                            check_rep=False)
+        sharded = jax.shard_map(body, mesh=mesh,
+                                in_specs=base_in + extra_specs,
+                                out_specs=base_out + extra_specs,
+                                check_vma=False)
         return sharded(params, opt_state, batch, lr, *extra_args)
 
     step.metrics = telemetry

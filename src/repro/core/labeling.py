@@ -592,7 +592,6 @@ def shard_label_round(public_logits, val_logits, topology: Topology,
     Topologies other than rings / complete graphs raise eagerly — run
     those rounds through the node-stacked :func:`label_round`.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = public_logits.shape[0]
@@ -618,9 +617,9 @@ def shard_label_round(public_logits, val_logits, topology: Topology,
                                        ring=ring, full=full)
         return vals, idx, w, id_mask, thresholds
 
-    vals, idx, w, id_mask, thresholds = shard_map(
+    vals, idx, w, id_mask, thresholds = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec),
-        out_specs=(spec, spec, spec, spec, spec), check_rep=False)(
+        out_specs=(spec, spec, spec, spec, spec), check_vma=False)(
             public_logits, val_logits)
     return SparseHomogenizedSet(distill.SparseLabels(vals, idx), w,
                                 id_mask, thresholds)
@@ -654,7 +653,6 @@ def shard_streaming_label_round(model, params, public_x, val_x,
     exchange still moves top-k payloads over the node axis only, so
     label wire bytes are unchanged by model parallelism (DESIGN.md §10).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.sharding import federation_specs, gather_model_tree
@@ -696,10 +694,10 @@ def shard_streaming_label_round(model, params, public_x, val_x,
                                        ring=ring, full=full)
         return vals, idx, w, id_mask, thresholds
 
-    vals, idx, w, id_mask, thresholds = shard_map(
+    vals, idx, w, id_mask, thresholds = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, P(), spec),
-        out_specs=(spec, spec, spec, spec, spec), check_rep=False)(
+        out_specs=(spec, spec, spec, spec, spec), check_vma=False)(
             params, chunks, val_x)
     return SparseHomogenizedSet(distill.SparseLabels(vals, idx), w,
                                 id_mask, thresholds)

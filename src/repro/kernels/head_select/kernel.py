@@ -18,12 +18,18 @@ reuses):
   which both detectors fall out at the final block (MSP ``1/z``,
   energy ``m + log z``);
 * ``tv, ti`` — running top-k *logits* + global vocab indices, merged
-  blockwise (iterative argmax inside the block, then a 2k-wide merge
-  with the carry). Top-k of the temperature softmax equals top-k of
+  with the block by k rounds of "take the larger of the carry's and
+  the block's best" (ties go to the carry, then to the lowest column —
+  ``argmax`` order). Top-k of the temperature softmax equals top-k of
   the logits (softmax is monotonic), and the *renormalized* top-k
   payload depends only on the top-k logits themselves —
   ``v_j = exp(l_j/T) / Σ_{j'∈topk} exp(l_j'/T)`` — so the temperature
   enters only in the finalizer and no softmax over C is ever formed.
+
+Mosaic layout: every per-row quantity is a ``(rows, 1)`` column and
+every reduction keeps its axis, so no rank-1 block, gather, stack or
+concatenate reaches the TPU compiler; argmax is a masked min over the
+column iota, and reading the carry's index at a slot is a masked max.
 
 VMEM per cell: ``block_rows × D`` hidden + ``D × block_c`` weights +
 ``block_rows × block_c`` scores (f32). At D=4k, block_c=512,
@@ -43,89 +49,140 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _head_kernel(h_ref, w_ref, b_ref, *refs,
-                 temperature: float, k: int, detector: str,
-                 block_c: int, num_c_blocks: int, num_classes: int,
-                 raw_stats: bool = False):
-    # outputs: (conf, vals, idx) or — raw_stats, for the model-axis
-    # merge — (m, z, tv, ti); the last four refs are always the
-    # (m, z, tv, ti) VMEM scratch carry.
-    out_refs, (m_scr, z_scr, tv_scr, ti_scr) = refs[:-4], refs[-4:]
+def _init_carry(m_scr, z_scr, tv_scr, ti_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    z_scr[...] = jnp.zeros_like(z_scr)
+    tv_scr[...] = jnp.full_like(tv_scr, NEG_INF)
+    ti_scr[...] = jnp.zeros_like(ti_scr)
+
+
+def _fold_block(s, col0, num_classes: int, k: int,
+                m_scr, z_scr, tv_scr, ti_scr):
+    """Fold one ``(bn, bc)`` f32 block of logits whose first column is
+    global vocab index ``col0`` into the running ``(m, z, tv, ti)``
+    carry. Columns at or past ``num_classes`` are padding."""
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(col0 + col < num_classes, s, NEG_INF)
+
+    # ---- online-softmax detector stats at T=1 (flash-attention carry)
+    m_prev = m_scr[...]                                    # (bn, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    z_scr[...] = (z_scr[...] * jnp.exp(m_prev - m_new)
+                  + jnp.sum(jnp.exp(s - m_new), axis=1, keepdims=True))
+    m_scr[...] = m_new
+
+    # ---- streaming top-k merge of the carry with the block's logits
+    tv, ti = tv_scr[...], ti_scr[...]                      # (bn, k)
+    slot = jax.lax.broadcasted_iota(jnp.int32, tv.shape, 1)
+    width = s.shape[1]
+    out_v = jnp.full(tv.shape, NEG_INF, jnp.float32)
+    out_i = jnp.zeros(ti.shape, jnp.int32)
+    for j in range(k):
+        cv = jnp.max(tv, axis=1, keepdims=True)
+        bv = jnp.max(s, axis=1, keepdims=True)
+        cp = jnp.min(jnp.where(tv == cv, slot, k), axis=1, keepdims=True)
+        bp = jnp.min(jnp.where(s == bv, col, width), axis=1, keepdims=True)
+        ci = jnp.max(jnp.where(slot == cp, ti, -1), axis=1, keepdims=True)
+        take_c = cv >= bv                                  # carry first
+        out_v = jnp.where(slot == j, jnp.where(take_c, cv, bv), out_v)
+        out_i = jnp.where(slot == j, jnp.where(take_c, ci, col0 + bp), out_i)
+        tv = jnp.where(take_c & (slot == cp), NEG_INF, tv)
+        s = jnp.where(jnp.logical_not(take_c) & (col == bp), NEG_INF, s)
+    tv_scr[...] = out_v
+    ti_scr[...] = out_i
+
+
+def _finalize(out_refs, m_scr, z_scr, tv_scr, ti_scr, *, temperature: float,
+              detector: str, raw_stats: bool):
+    """Write the outputs from the carry: the raw carry itself
+    (``raw_stats``) or ``(conf, vals, idx)``."""
+    if raw_stats:
+        # vocab-sharded path: ship the raw carry; the caller merges
+        # (m, z) and the top-k logits across model-axis shards with the
+        # same streaming math (ref.merge_head_stats) and only then
+        # applies the detector / temperature finalizer.
+        for ref, scr in zip(out_refs, (m_scr, z_scr, tv_scr, ti_scr)):
+            ref[...] = scr[...]
+        return
+    conf_ref, vals_ref, idx_ref = out_refs
+    z = jnp.maximum(z_scr[...], 1e-30)
+    if detector == "energy":
+        conf_ref[...] = m_scr[...] + jnp.log(z)
+    else:
+        conf_ref[...] = 1.0 / z
+    tv = tv_scr[...]                                       # sorted desc
+    e = jnp.exp((tv - jnp.max(tv, axis=1, keepdims=True)) / temperature)
+    vals_ref[...] = e / jnp.maximum(jnp.sum(e, axis=1, keepdims=True), 1e-30)
+    idx_ref[...] = ti_scr[...]
+
+
+def _select_kernel(*refs, scores, num_inputs: int, temperature: float,
+                   k: int, detector: str, block_c: int, num_c_blocks: int,
+                   num_classes: int, raw_stats: bool):
+    # refs: the inputs, then the outputs — (conf, vals, idx) or, with
+    # raw_stats, (m, z, tv, ti) — then the (m, z, tv, ti) VMEM carry
+    in_refs, out_refs = refs[:num_inputs], refs[num_inputs:-4]
+    carry = refs[-4:]
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        z_scr[...] = jnp.zeros_like(z_scr)
-        tv_scr[...] = jnp.full_like(tv_scr, NEG_INF)
-        ti_scr[...] = jnp.zeros_like(ti_scr)
+        _init_carry(*carry)
 
-    h = h_ref[...].astype(jnp.float32)                     # (bn, D)
-    w = w_ref[...].astype(jnp.float32)                     # (D, bc)
-    s = jax.lax.dot_general(h, w, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + b_ref[...].astype(jnp.float32)                 # (1, bc) bias
-    col0 = ci * block_c
-    local = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(col0 + local < num_classes, s, NEG_INF)  # C padding
-
-    # ---- online-softmax detector stats at T=1 (flash-attention carry)
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    z_scr[...] = (z_scr[...] * jnp.exp(m_prev - m_new)
-                  + jnp.sum(jnp.exp(s - m_new[:, None]), axis=1))
-    m_scr[...] = m_new
-
-    # ---- block top-k of the raw logits by iterative argmax (k small)
-    work = s
-    bv_list, bi_list = [], []
-    for _ in range(k):
-        v = jnp.max(work, axis=-1)
-        i = jnp.argmax(work, axis=-1).astype(jnp.int32)
-        bv_list.append(v)
-        bi_list.append(col0 + i)
-        work = jnp.where(local == i[:, None], NEG_INF, work)
-    bv = jnp.stack(bv_list, axis=-1)                       # (bn, k)
-    bi = jnp.stack(bi_list, axis=-1)
-
-    # ---- streaming merge with the carry: top-k of the 2k candidates
-    cv = jnp.concatenate([tv_scr[...], bv], axis=-1)       # (bn, 2k)
-    cidx = jnp.concatenate([ti_scr[...], bi], axis=-1)
-    slot = jax.lax.broadcasted_iota(jnp.int32, cv.shape, 1)
-    mv_list, mi_list = [], []
-    for _ in range(k):
-        v = jnp.max(cv, axis=-1)
-        p = jnp.argmax(cv, axis=-1)
-        mv_list.append(v)
-        mi_list.append(jnp.take_along_axis(cidx, p[:, None], axis=-1)[:, 0])
-        cv = jnp.where(slot == p[:, None], NEG_INF, cv)
-    tv_scr[...] = jnp.stack(mv_list, axis=-1)
-    ti_scr[...] = jnp.stack(mi_list, axis=-1)
+    _fold_block(scores(*in_refs), ci * block_c, num_classes, k, *carry)
 
     @pl.when(ci == num_c_blocks - 1)
-    def _finalize():
-        if raw_stats:
-            # vocab-sharded path: ship the raw carry; the caller merges
-            # (m, z) and the top-k logits across model-axis shards with
-            # the same streaming math (ref.merge_head_stats) and only
-            # then applies the detector / temperature finalizer.
-            m_ref, z_ref, tv_ref, ti_ref = out_refs
-            m_ref[...] = m_scr[...]
-            z_ref[...] = z_scr[...]
-            tv_ref[...] = tv_scr[...]
-            ti_ref[...] = ti_scr[...]
-            return
-        conf_ref, vals_ref, idx_ref = out_refs
-        z = jnp.maximum(z_scr[...], 1e-30)
-        if detector == "energy":
-            conf_ref[...] = m_scr[...] + jnp.log(z)
-        else:
-            conf_ref[...] = 1.0 / z
-        tv = tv_scr[...]                                   # sorted desc
-        e = jnp.exp((tv - tv[:, :1]) / temperature)
-        vals_ref[...] = e / jnp.maximum(jnp.sum(e, -1, keepdims=True),
-                                        1e-30)
-        idx_ref[...] = ti_scr[...]
+    def _write():
+        _finalize(out_refs, *carry, temperature=temperature,
+                  detector=detector, raw_stats=raw_stats)
+
+
+def select_call(scores, inputs, in_specs, *, rows: int, num_classes: int,
+                block_rows: int, block_c: int, k: int, temperature: float,
+                detector: str, raw_stats: bool, interpret: bool):
+    """The ``(row, vocab)``-grid pallas_call shared by ``head_select``
+    and ``msp_select``: ``scores(*in_refs)`` yields each cell's
+    ``(block_rows, block_c)`` f32 logits, folded into the carry.
+
+    A ragged last vocab block reads past ``num_classes``; the fold masks
+    those columns, so no input is ever padded. Per-row outputs are
+    ``(rows, 1)`` columns in the kernel (Mosaic takes no rank-1 block
+    smaller than 128) and come back as ``(rows,)``."""
+    num_c_blocks = pl.cdiv(num_classes, block_c)
+    kernel = functools.partial(
+        _select_kernel, scores=scores, num_inputs=len(inputs),
+        temperature=temperature, k=k, detector=detector, block_c=block_c,
+        num_c_blocks=num_c_blocks, num_classes=num_classes,
+        raw_stats=raw_stats)
+    col_spec = pl.BlockSpec((block_rows, 1), lambda i, c: (i, 0))
+    topk_spec = pl.BlockSpec((block_rows, k), lambda i, c: (i, 0))
+    col = jax.ShapeDtypeStruct((rows, 1), jnp.float32)
+    tv = jax.ShapeDtypeStruct((rows, k), jnp.float32)
+    ti = jax.ShapeDtypeStruct((rows, k), jnp.int32)
+    n_cols = 2 if raw_stats else 1
+    outs = pl.pallas_call(
+        kernel,
+        grid=(rows // block_rows, num_c_blocks),
+        in_specs=in_specs,
+        out_specs=(col_spec,) * n_cols + (topk_spec, topk_spec),
+        out_shape=(col,) * n_cols + (tv, ti),
+        scratch_shapes=[pltpu.VMEM((block_rows, 1), jnp.float32),
+                        pltpu.VMEM((block_rows, 1), jnp.float32),
+                        pltpu.VMEM((block_rows, k), jnp.float32),
+                        pltpu.VMEM((block_rows, k), jnp.int32)],
+        interpret=interpret,
+    )(*inputs)
+    return tuple(o[:, 0] for o in outs[:n_cols]) + tuple(outs[n_cols:])
+
+
+def _head_scores(h_ref, w_ref, b_ref):
+    # bf16 × bf16 products are exact in f32, so a same-dtype matmul with
+    # f32 accumulation equals the f32 matmul of the upcast operands
+    dt = jnp.promote_types(h_ref.dtype, w_ref.dtype)
+    s = jax.lax.dot_general(h_ref[...].astype(dt), w_ref[...].astype(dt),
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return s + b_ref[...].astype(jnp.float32)              # (1, bc) bias
 
 
 def head_select_pallas(hidden, w, bias, *, temperature: float, k: int = 8,
@@ -147,51 +204,13 @@ def head_select_pallas(hidden, w, bias, *, temperature: float, k: int = 8,
     block_rows = min(block_rows, N)
     assert N % block_rows == 0, "pad rows to a block multiple"
     block_c = min(block_c, C)
-    pad_c = (-C) % block_c
     if bias is None:
         bias = jnp.zeros((C,), jnp.float32)
-    if pad_c:
-        w = jnp.pad(w, ((0, 0), (0, pad_c)))
-        bias = jnp.pad(bias, (0, pad_c))
-    bias = bias.reshape(1, -1)
-    num_c_blocks = (C + pad_c) // block_c
-
-    kernel = functools.partial(
-        _head_kernel, temperature=temperature, k=k, detector=detector,
-        block_c=block_c, num_c_blocks=num_c_blocks, num_classes=C,
-        raw_stats=raw_stats)
-    row_spec = pl.BlockSpec((block_rows,), lambda i, c: (i,))
-    topk_spec = pl.BlockSpec((block_rows, k), lambda i, c: (i, 0))
-    if raw_stats:
-        out_specs = (row_spec, row_spec, topk_spec, topk_spec)
-        out_shape = (
-            jax.ShapeDtypeStruct((N,), jnp.float32),
-            jax.ShapeDtypeStruct((N,), jnp.float32),
-            jax.ShapeDtypeStruct((N, k), jnp.float32),
-            jax.ShapeDtypeStruct((N, k), jnp.int32),
-        )
-    else:
-        out_specs = (row_spec, topk_spec, topk_spec)
-        out_shape = (
-            jax.ShapeDtypeStruct((N,), jnp.float32),
-            jax.ShapeDtypeStruct((N, k), jnp.float32),
-            jax.ShapeDtypeStruct((N, k), jnp.int32),
-        )
-    return pl.pallas_call(
-        kernel,
-        grid=(N // block_rows, num_c_blocks),
-        in_specs=[
-            pl.BlockSpec((block_rows, D), lambda i, c: (i, 0)),
-            pl.BlockSpec((D, block_c), lambda i, c: (0, c)),
-            pl.BlockSpec((1, block_c), lambda i, c: (0, c)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows, k), jnp.float32),
-            pltpu.VMEM((block_rows, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(hidden, w, bias)
+    in_specs = [pl.BlockSpec((block_rows, D), lambda i, c: (i, 0)),
+                pl.BlockSpec((D, block_c), lambda i, c: (0, c)),
+                pl.BlockSpec((1, block_c), lambda i, c: (0, c))]
+    return select_call(_head_scores, (hidden, w, bias.reshape(1, -1)),
+                       in_specs, rows=N, num_classes=C,
+                       block_rows=block_rows, block_c=block_c, k=k,
+                       temperature=temperature, detector=detector,
+                       raw_stats=raw_stats, interpret=interpret)

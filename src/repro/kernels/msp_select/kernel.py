@@ -7,61 +7,32 @@ max; softmax@T → top_k); this kernel does one pass with everything
 fused in VMEM. The D_ID membership bit is *not* computed here: the
 threshold is ROC-calibrated from the confidences downstream, so the
 mask is one compare the caller owns (``conf > t_opt``) — see
-``kernels/head_select`` for the vocab-tiled generalization that starts
-from hidden states instead of logits.
+``kernels/head_select`` for the generalization that starts from hidden
+states instead of logits.
 
-Tiling: (block_n × C) row tiles — the vocab axis stays resident in VMEM
-(256k vocab ≈ 1 MB/row in f32, so block_n is chosen so block_n × C × 4B
-fits comfortably; 8 rows × 257k ≈ 8 MB). Top-k (k ≤ 16) is computed by
-iterative argmax on the VMEM tile — k sequential VPU max-reductions beat
-a full sort at these k.
+Tiling: ``(block_n, block_c)`` tiles over a ``(row, vocab)`` grid. A
+whole row of a 152k vocabulary is ≈ 0.6 MB in f32, so resident
+``(8, C)`` rows and their temporaries overflow the TPU's scoped VMEM;
+the vocab axis is therefore folded block by block into the same
+online-softmax + streaming top-k carry as ``head_select`` (which this
+kernel shares). Top-k of the temperature softmax equals top-k of the
+logits, and the renormalized payload depends only on those k logits,
+so the temperature enters only in the finalizer.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-NEG_INF = -1e30
+from repro.kernels.head_select.kernel import select_call
 
 
-def _msp_kernel(logits_ref, conf_ref, vals_ref, idx_ref, *,
-                temperature: float, k: int, detector: str):
-    lf = logits_ref[...].astype(jnp.float32)               # (bn, C)
-    # detector confidence at T=1 from one stable softmax reduction:
-    # MSP = exp(0)/Σexp(lf−m1); energy = logsumexp = m1 + log Σexp(lf−m1)
-    m1 = jnp.max(lf, axis=-1, keepdims=True)
-    z1 = jnp.sum(jnp.exp(lf - m1), axis=-1)
-    if detector == "energy":
-        conf = m1[:, 0] + jnp.log(jnp.maximum(z1, 1e-30))
-    else:
-        conf = 1.0 / jnp.maximum(z1, 1e-30)
-    conf_ref[...] = conf
-    # temperature softmax for the soft labels
-    lT = lf / temperature
-    mT = jnp.max(lT, axis=-1, keepdims=True)
-    eT = jnp.exp(lT - mT)
-    zT = jnp.sum(eT, axis=-1, keepdims=True)
-    probs = eT / jnp.maximum(zT, 1e-30)                    # (bn, C)
+# vocab columns per grid cell: an (8, 2048) f32 tile is 64 KB of VMEM
+BLOCK_C = 2048
 
-    # iterative top-k by repeated argmax (k small)
-    work = probs
-    total = jnp.zeros((probs.shape[0],), jnp.float32)
-    vals_list, idx_list = [], []
-    cols = jax.lax.broadcasted_iota(jnp.int32, probs.shape, 1)
-    for j in range(k):
-        v = jnp.max(work, axis=-1)
-        i = jnp.argmax(work, axis=-1).astype(jnp.int32)
-        vals_list.append(v)
-        idx_list.append(i)
-        total = total + v
-        work = jnp.where(cols == i[:, None], NEG_INF, work)
-    vals = jnp.stack(vals_list, axis=-1)                   # (bn, k)
-    idx = jnp.stack(idx_list, axis=-1)
-    vals_ref[...] = vals / jnp.maximum(total, 1e-9)[:, None]
-    idx_ref[...] = idx
+
+def _logit_scores(logits_ref):
+    return logits_ref[...].astype(jnp.float32)
 
 
 def msp_select_pallas(logits, *, temperature: float, k: int = 8,
@@ -69,24 +40,13 @@ def msp_select_pallas(logits, *, temperature: float, k: int = 8,
                       detector: str = "msp"):
     """logits: (N, C) -> (conf (N,), vals (N, k), idx (N, k))."""
     N, C = logits.shape
+    assert k <= C, "clamp k to the class count before calling"
     block_n = min(block_n, N)
     assert N % block_n == 0, "pad rows to a block multiple"
     assert detector in ("msp", "energy"), detector
-    kernel = functools.partial(_msp_kernel, temperature=temperature,
-                               k=k, detector=detector)
-    return pl.pallas_call(
-        kernel,
-        grid=(N // block_n,),
-        in_specs=[pl.BlockSpec((block_n, C), lambda i: (i, 0))],
-        out_specs=(
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((N,), jnp.float32),
-            jax.ShapeDtypeStruct((N, k), jnp.float32),
-            jax.ShapeDtypeStruct((N, k), jnp.int32),
-        ),
-        interpret=interpret,
-    )(logits)
+    block_c = min(BLOCK_C, C)
+    in_specs = [pl.BlockSpec((block_n, block_c), lambda i, c: (i, c))]
+    return select_call(_logit_scores, (logits,), in_specs, rows=N,
+                       num_classes=C, block_rows=block_n, block_c=block_c,
+                       k=k, temperature=temperature, detector=detector,
+                       raw_stats=False, interpret=interpret)

@@ -3,9 +3,12 @@
 Runs the full IDKD pipeline on token data: node-stacked params, per-node
 private corpus shards (Dirichlet over topics), QG-DSGDm-N gossip steps,
 and periodic IDKD homogenization rounds with top-k sparse soft labels on a
-public corpus. On CPU this drives reduced configs end-to-end; on a TPU
-cluster the same functions run under the production mesh (dryrun.py proves
-the latter lowers + compiles for every assigned arch × shape).
+public corpus. On CPU this drives reduced configs end-to-end. On a TPU
+the same functions run unchanged: ``chip_smoke.py`` at the repo root runs
+them on one v5e chip at qwen3-1.7b's published widths (depth cut), and
+``tests/test_tpu_compile.py`` compiles the label-round kernels for v5e
+without a chip. ``dryrun.py`` compiles for forced CPU devices, so it says
+nothing about a TPU.
 
 The step loop is the unified on-device driver (``core.driver``): one
 ``make_step`` per phase (plain LM / LM + sparse-KD), per-node batch
@@ -61,6 +64,7 @@ from repro.core.mixing import (Mixer, make_mixer, normalize_compression,
 from repro.core.topology import Topology
 from repro.data.dirichlet import dirichlet_partition
 from repro.data.synthetic import make_lm_data
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import consensus_params, stack_params
 from repro.models import build_model
 from repro.obs import log as obs_log
@@ -290,6 +294,11 @@ def run_training(cfg: ModelConfig, tcfg: TrainConfig, *, seq_len: int = 64,
     metrics-bus / trace-span layers for this run (DESIGN.md §11); the
     trajectory is bitwise identical with it on or off.
 
+    Returns the consensus ``params``, the node-stacked ``node_params``
+    as the runners left them (placed on the shard mesh under
+    ``driver_mode="shard"``), the eval ``loss_history``, the model,
+    topology, ledger and schedule.
+
     ``resil`` (a :class:`repro.resil.Resilience`) turns on the
     resilience layer (DESIGN.md §12): health guards + quarantine,
     durable snapshots with auto-resume, rollback-on-divergence. A
@@ -394,9 +403,9 @@ def run_training(cfg: ModelConfig, tcfg: TrainConfig, *, seq_len: int = 64,
         elem_bytes=sched.wire_elem_bytes(wire_dtype, cfg.dtype),
         payload_elems=payload_elems, index_bytes=index_bytes,
         telemetry=telemetry, resil=resil)
-    return {"params": consensus_params(params), "loss_history": history,
-            "model": model, "topology": topo, "ledger": ledger.as_dict(),
-            "schedule": schedule}
+    return {"params": consensus_params(params), "node_params": params,
+            "loss_history": history, "model": model, "topology": topo,
+            "ledger": ledger.as_dict(), "schedule": schedule}
 
 
 def main():
@@ -471,6 +480,7 @@ def main():
                          "and re-run with the offender quarantined "
                          "(implies --guards)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace and not args.telemetry:
         ap.error("--trace needs --telemetry DIR for the output location")
     cfg = get_config(args.arch)

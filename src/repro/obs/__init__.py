@@ -56,8 +56,8 @@ class Telemetry:
         if meta:
             self.event("run_meta", **meta)
         if jax_profile and self.out_dir is not None:
-            self._profiling = start_jax_profiler(
-                self.out_dir / "jax_profile")
+            start_jax_profiler(self.out_dir / "jax_profile")
+            self._profiling = True
 
     # -- sinks ---------------------------------------------------------------
     def event(self, ev: str, **fields) -> None:

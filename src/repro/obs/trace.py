@@ -9,9 +9,8 @@ of a freshly built runner is tagged ``compile=True`` so XLA compilation
 cost is visible as a distinct slice.
 
 For device-level detail, :func:`start_jax_profiler` hands off to
-``jax.profiler`` (TensorBoard/Perfetto-compatible output) when the
-installed jax supports it; the hand-off is best-effort and never fails
-a run.
+``jax.profiler`` (TensorBoard/Perfetto-compatible output). A run that
+asks for a device trace and cannot start or stop one fails loudly.
 """
 from __future__ import annotations
 
@@ -20,7 +19,7 @@ import os
 import time
 from contextlib import contextmanager
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 
 class TraceRecorder:
@@ -96,19 +95,13 @@ def validate_trace(path) -> int:
     return len(events)
 
 
-def start_jax_profiler(log_dir) -> bool:
-    """Best-effort ``jax.profiler.start_trace`` hand-off (device detail)."""
-    try:
-        import jax
-        jax.profiler.start_trace(str(log_dir))
-        return True
-    except Exception:
-        return False
+def start_jax_profiler(log_dir) -> None:
+    """``jax.profiler.start_trace`` hand-off (device detail); a profiler
+    failure raises."""
+    import jax
+    jax.profiler.start_trace(str(log_dir))
 
 
 def stop_jax_profiler() -> None:
-    try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception:
-        pass
+    import jax
+    jax.profiler.stop_trace()

@@ -233,7 +233,6 @@ def test_shard_twin_matches_stacked(topo_name, comp, gossip):
     compressed trajectory to float tolerance (same estimates, same
     payload selection) — over however many host devices divide the node
     axis (1 device → degenerate block mesh, same code path)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
     from repro.launch.sharding import node_stacked_specs
     n = 4
@@ -259,8 +258,8 @@ def test_shard_twin_matches_stacked(topo_name, comp, gossip):
 
     sx = node_stacked_specs(tree, n, "node")
     sc = node_stacked_specs(comm, n, "node")
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(sx, sc),
-                          out_specs=(sx, sc), check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(sx, sc),
+                              out_specs=(sx, sc), check_vma=False))
     xp = tree
     for _ in range(3):
         xp, comm = f(xp, comm)

@@ -20,7 +20,8 @@ def _check(logits, T, k, block_n=4):
     assert (np.asarray(idx) == np.asarray(ir)).all()
 
 
-@pytest.mark.parametrize("N,C,k", [(16, 64, 4), (8, 1024, 8), (32, 257, 2)])
+@pytest.mark.parametrize("N,C,k", [(16, 64, 4), (8, 1024, 8), (32, 257, 2),
+                                   (8, 5000, 8)])   # ragged vocab tiles
 @pytest.mark.parametrize("T", [1.0, 10.0])
 def test_msp_select_matches_ref(N, C, k, T):
     logits = jnp.asarray(np.random.default_rng(N + C).normal(size=(N, C)) * 4,

@@ -125,3 +125,49 @@ def test_reduced_variant_bounds():
         assert r.d_model <= 512
         if r.moe.enabled:
             assert r.moe.num_experts <= 4
+
+
+# ---------------------------------------------------------- compile cache
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    """Without JAX_COMPILATION_CACHE_DIR the entry points keep the cache
+    in a fixed directory of the checkout."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = enable_compile_cache()
+        assert got == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_dir_is_used(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiled programs land there
+    and the helper points nowhere else."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    checkout = Path(__file__).resolve().parents[1]
+    default = checkout / ".jax_cache"
+    before = sorted(default.iterdir()) if default.exists() else []
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PLATFORMS="cpu", PYTHONPATH=str(checkout / "src"))
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "d = enable_compile_cache()\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n"
+            "print(d, jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(tmp_path / "cc")] * 2
+    assert any((tmp_path / "cc").iterdir())
+    after = sorted(default.iterdir()) if default.exists() else []
+    assert after == before

@@ -154,13 +154,12 @@ def test_ppermute_errors_name_the_fallback_backend():
 def _shard_mix(mixer, tree, n_local):
     """Run a shard_map mixer on node-stacked data over however many
     devices divide the node axis (1 device → degenerate block mesh)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     n = jax.tree.leaves(tree)[0].shape[0]
     size = n // n_local
     mesh = Mesh(np.asarray(jax.devices()[:size]), ("node",))
-    return jax.jit(shard_map(mixer, mesh=mesh, in_specs=(P("node"),),
-                             out_specs=P("node"), check_rep=False))(tree)
+    return jax.jit(jax.shard_map(mixer, mesh=mesh, in_specs=(P("node"),),
+                                 out_specs=P("node"), check_vma=False))(tree)
 
 
 @pytest.mark.parametrize("n", [2, 3, 8])

@@ -21,6 +21,8 @@ from repro.configs.base import IDKDConfig, TrainConfig
 from repro.obs import (EVENT_SCHEMA, RunLog, Telemetry, TraceRecorder, log,
                        read_events, validate_runlog, validate_trace)
 
+from jaxpr_audit import dense_stack_avals
+
 N = 4
 
 
@@ -109,6 +111,17 @@ def test_validate_trace_rejects_malformed(tmp_path):
         validate_trace(p)
 
 
+def test_jax_profile_failure_raises(tmp_path, monkeypatch):
+    """A run that asks for a device trace fails when the profiler does
+    not start, instead of running on without one."""
+    def refuse(log_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        Telemetry(tmp_path, events=False, jax_profile=True)
+
+
 # ----------------------------------------------------- obs.check CLI
 def test_check_cli(tmp_path):
     from repro.obs.check import main
@@ -148,27 +161,6 @@ def test_metrics_update_matches_consensus_distance():
 
 
 # ------------------------------------------------------- jaxpr audit
-def _iter_avals(jaxpr):
-    for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            if hasattr(v, "aval"):
-                yield v.aval
-        for p in eqn.params.values():
-            for sub in (p if isinstance(p, (list, tuple)) else [p]):
-                inner = getattr(sub, "jaxpr", None)
-                if isinstance(sub, jax.core.Jaxpr):
-                    yield from _iter_avals(sub)
-                elif inner is not None and isinstance(inner,
-                                                      jax.core.Jaxpr):
-                    yield from _iter_avals(inner)
-
-
-def _dense_stack_avals(jaxpr, P, C):
-    return [a.shape for a in _iter_avals(jaxpr)
-            if getattr(a, "shape", ()) and a.shape[-1] == C
-            and P in a.shape[:-1]]
-
-
 def test_telemetry_step_jaxpr_has_no_public_stack():
     """Extending the PR 5 audit: the metrics update rides the KD step
     without materializing anything shaped like the full public logit
@@ -208,7 +200,7 @@ def test_telemetry_step_jaxpr_has_no_public_stack():
     }
     jx = jax.make_jaxpr(step)(params, opt, batch,
                               jnp.asarray(0.1, jnp.float32), m0)
-    assert not _dense_stack_avals(jx.jaxpr, P, cfg.vocab_size)
+    assert not dense_stack_avals(jx.jaxpr, P, cfg.vocab_size)
 
 
 # ---------------------------------------- on/off trajectory invariance

@@ -33,6 +33,8 @@ from repro.core.topology import Topology
 from repro.kernels.head_select import head_select, head_select_ref
 from repro.models import build_model
 
+from jaxpr_audit import dense_stack_avals
+
 N = 4
 
 
@@ -342,31 +344,6 @@ def test_shard_streaming_2d_mesh_matches_stacked(request, setup_name, C):
 
 
 # --------------------------------------------------------- jaxpr audit
-def _iter_avals(jaxpr):
-    """Every intermediate aval in a jaxpr, sub-jaxprs (scan bodies,
-    branches, pjit calls) included."""
-    for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            if hasattr(v, "aval"):
-                yield v.aval
-        for p in eqn.params.values():
-            for sub in (p if isinstance(p, (list, tuple)) else [p]):
-                inner = getattr(sub, "jaxpr", None)
-                if isinstance(sub, jax.core.Jaxpr):
-                    yield from _iter_avals(sub)
-                elif inner is not None and isinstance(inner,
-                                                      jax.core.Jaxpr):
-                    yield from _iter_avals(inner)
-
-
-def _dense_stack_avals(jaxpr, P, C):
-    """Intermediates that hold a public logit stack: last dim C with the
-    full public axis P also present (e.g. (n, P, C) or (n, P, S, C))."""
-    return [a.shape for a in _iter_avals(jaxpr)
-            if getattr(a, "shape", ()) and a.shape[-1] == C
-            and P in a.shape[:-1]]
-
-
 def test_streaming_jaxpr_has_no_dense_stack(cls_setup, lm_setup):
     """The shape audit: no (n, P, C)- or (n, P, S, V)-shaped intermediate
     anywhere in the streaming round's jaxpr — validated against the
@@ -379,12 +356,12 @@ def test_streaming_jaxpr_has_no_dense_stack(cls_setup, lm_setup):
         stream_jaxpr = jax.make_jaxpr(
             lambda pr, pb, vl: labeling.streaming_label_round(
                 model, pr, pb, vl, topo, cfg))(params, pub, val)
-        assert not _dense_stack_avals(stream_jaxpr.jaxpr, P, C), \
-            _dense_stack_avals(stream_jaxpr.jaxpr, P, C)
+        assert not dense_stack_avals(stream_jaxpr.jaxpr, P, C), \
+            dense_stack_avals(stream_jaxpr.jaxpr, P, C)
         one_shot_jaxpr = jax.make_jaxpr(
             lambda pr, pb, vl: _one_shot(model, pr, pb, vl, topo, cfg))(
                 params, pub, val)
-        assert _dense_stack_avals(one_shot_jaxpr.jaxpr, P, C), \
+        assert dense_stack_avals(one_shot_jaxpr.jaxpr, P, C), \
             "audit is blind: one-shot stack not detected"
 
 
@@ -399,7 +376,7 @@ def test_shard_streaming_jaxpr_has_no_dense_stack(cls_setup):
         lambda pr, pb, vl: labeling.shard_streaming_label_round(
             model, pr, pb, vl, topo, cfg, mesh=make_node_mesh(N)))(
                 params, pub, val)
-    assert not _dense_stack_avals(jx.jaxpr, pub.shape[0], 10)
+    assert not dense_stack_avals(jx.jaxpr, pub.shape[0], 10)
 
 
 # --------------------------------------- end-to-end trajectory equality
