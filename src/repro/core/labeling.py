@@ -66,6 +66,7 @@ from repro.kernels.head_select import (NEG_INF, head_select, head_select_ref,
                                        head_select_stats_ref,
                                        merge_head_stats)
 from repro.kernels.msp_select import msp_select, msp_select_ref
+from repro.obs.trace import span
 
 BACKENDS = ("dense", "fused", "sparse")
 DEFAULT_TOPK = 8
@@ -154,27 +155,29 @@ def exchange_sparse(topology: Topology, id_mask, sparse: distill.SparseLabels
     over the scatter, so it equals the *concatenation* of the
     contributors' (values · m_j / cnt, indices) pairs along the k axis.
     Output k_out = (max_degree + 1) · k with zero-valued padding slots;
-    O(Σ deg · P · k) work and bytes.
+    O(Σ deg · P · k) work and bytes. Its operations carry the name
+    scope ``exchange``.
     """
-    nbr, valid = topology.neighbor_arrays()
-    nbr = jnp.asarray(nbr)
-    valid = jnp.asarray(valid)
-    m = id_mask.astype(jnp.float32)
-    w = m[nbr] * valid[:, :, None]                         # (n, D, P)
-    cnt = jnp.sum(w, axis=1)                               # (n, P)
-    share = w / jnp.maximum(cnt, 1.0)[:, None, :]
-    vals = sparse.values[nbr]                              # (n, D, P[, S], k)
-    idx = sparse.indices[nbr]
-    extra = vals.ndim - share.ndim                         # e.g. the S axis
-    vals = vals * share.reshape(share.shape + (1,) * extra)
-    # merge the contributor axis into k: (n, P[, S], D·k)
-    vals = jnp.moveaxis(vals, 1, -2)
-    idx = jnp.moveaxis(idx, 1, -2)
-    vals = vals.reshape(vals.shape[:-2] + (-1,))
-    idx = idx.reshape(idx.shape[:-2] + (-1,))
-    return (distill.SparseLabels(vals.astype(jnp.float32),
-                                 idx.astype(jnp.int32)),
-            (cnt > 0).astype(jnp.float32))
+    with jax.named_scope("exchange"):
+        nbr, valid = topology.neighbor_arrays()
+        nbr = jnp.asarray(nbr)
+        valid = jnp.asarray(valid)
+        m = id_mask.astype(jnp.float32)
+        w = m[nbr] * valid[:, :, None]                     # (n, D, P)
+        cnt = jnp.sum(w, axis=1)                           # (n, P)
+        share = w / jnp.maximum(cnt, 1.0)[:, None, :]
+        vals = sparse.values[nbr]                    # (n, D, P[, S], k)
+        idx = sparse.indices[nbr]
+        extra = vals.ndim - share.ndim                     # e.g. the S axis
+        vals = vals * share.reshape(share.shape + (1,) * extra)
+        # merge the contributor axis into k: (n, P[, S], D·k)
+        vals = jnp.moveaxis(vals, 1, -2)
+        idx = jnp.moveaxis(idx, 1, -2)
+        vals = vals.reshape(vals.shape[:-2] + (-1,))
+        idx = idx.reshape(idx.shape[:-2] + (-1,))
+        return (distill.SparseLabels(vals.astype(jnp.float32),
+                                     idx.astype(jnp.int32)),
+                (cnt > 0).astype(jnp.float32))
 
 
 # ------------------------------------------------------------ fused pass
@@ -339,14 +342,16 @@ def _stream_public(model, params, chunks, P: int, cfg: IDKDConfig, k: int,
                    head_pass=_head_pass):
     """Scan the chunked public set through the fused head pass for a
     (possibly local) block of nodes; accumulate only (conf, vals, idx).
-    ``head_pass`` swaps in the vocab-sharded pass on the 2-D mesh.
+    ``head_pass`` swaps in the vocab-sharded pass on the 2-D mesh. The
+    scan body's operations carry the name scope ``public_pass``.
     """
     L = jax.tree.leaves(params)[0].shape[0]
 
     def one_chunk(xc):                                     # (mb, ...)
         xb = jnp.broadcast_to(xc[None], (L,) + xc.shape)
-        return jax.vmap(
-            lambda p, x: head_pass(model, p, x, cfg, k))(params, xb)
+        with jax.named_scope("public_pass"):
+            return jax.vmap(
+                lambda p, x: head_pass(model, p, x, cfg, k))(params, xb)
 
     _, (conf, vals, idx) = jax.lax.scan(
         lambda carry, xc: (carry, one_chunk(xc)), None, chunks)
@@ -362,10 +367,12 @@ def _stream_public(model, params, chunks, P: int, cfg: IDKDConfig, k: int,
 def _stream_val_conf(model, params, val_x, cfg: IDKDConfig,
                      head_pass=_head_pass):
     """Per-node detector confidence on each node's own (small) val set,
-    through the same fused head pass (k=1: only conf is consumed)."""
-    return jax.vmap(
-        lambda p, x: head_pass(model, p, x, cfg, 1)[0])(
-            params, jnp.asarray(val_x))
+    through the same fused head pass (k=1: only conf is consumed); its
+    operations carry the name scope ``calibration_pass``."""
+    with jax.named_scope("calibration_pass"):
+        return jax.vmap(
+            lambda p, x: head_pass(model, p, x, cfg, 1)[0])(
+                params, jnp.asarray(val_x))
 
 
 # ------------------------------------------------------------ full round
@@ -465,6 +472,12 @@ def streaming_label_round(model, params, public_x, val_x,
     labels — the wire format the streaming path exists to preserve.
     ``filter_ood`` / ``active`` behave exactly as in
     :func:`label_round`.
+
+    Its host phases are :func:`repro.obs.trace.span`s, each around the
+    tracing, lowering and dispatch of its device work (none reads a
+    device value back): ``idkd.public_pass``,
+    ``idkd.calibration_pass``, ``idkd.threshold`` (calibrate and mask)
+    and ``idkd.exchange``.
     """
     n = jax.tree.leaves(params)[0].shape[0]
     if topology.n != n:
@@ -472,22 +485,27 @@ def streaming_label_round(model, params, public_x, val_x,
                          f"{topology.name!r} has {topology.n}")
     C = _head_width(model, params)
     k = min(cfg.label_topk or DEFAULT_TOPK, C)
-    chunks, P, _ = _chunk_public(public_x, cfg.stream_microbatch)
-    conf_pub, sparse = _stream_public(model, params, chunks, P, cfg, k)
+    with span("idkd.public_pass"):
+        chunks, P, _ = _chunk_public(public_x, cfg.stream_microbatch)
+        conf_pub, sparse = _stream_public(model, params, chunks, P, cfg, k)
 
     if filter_ood:
-        conf_val = _stream_val_conf(model, params, val_x, cfg)
-        thresholds = calibrate(conf_val, conf_pub)
-        id_mask = conf_pub > thresholds[:, None]
-    else:
-        thresholds = jnp.zeros((n,), jnp.float32)
-        id_mask = jnp.ones(conf_pub.shape, bool)
-    if active is not None:
-        act = jnp.asarray(active, bool)
-        id_mask = id_mask & act[:, None]
-    merged, weights = exchange_sparse(topology, id_mask, sparse)
-    if active is not None:
-        weights = weights * act[:, None]
+        with span("idkd.calibration_pass"):
+            conf_val = _stream_val_conf(model, params, val_x, cfg)
+    with span("idkd.threshold"):
+        if filter_ood:
+            thresholds = calibrate(conf_val, conf_pub)
+            id_mask = conf_pub > thresholds[:, None]
+        else:
+            thresholds = jnp.zeros((n,), jnp.float32)
+            id_mask = jnp.ones(conf_pub.shape, bool)
+        if active is not None:
+            act = jnp.asarray(active, bool)
+            id_mask = id_mask & act[:, None]
+    with span("idkd.exchange"):
+        merged, weights = exchange_sparse(topology, id_mask, sparse)
+        if active is not None:
+            weights = weights * act[:, None]
     return SparseHomogenizedSet(merged, weights, id_mask, thresholds)
 
 

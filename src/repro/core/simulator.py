@@ -52,6 +52,8 @@ from repro.core.topology import Topology
 from repro.data.dirichlet import dirichlet_partition, partition_stats
 from repro.data.synthetic import ClassificationData
 from repro.models import build_model
+from repro.obs.compile_path import CompileWatch
+from repro.obs.trace import span
 from repro.optim.schedules import step_decay
 
 
@@ -180,6 +182,18 @@ class _SimFederation(sched.CompiledFederationHooks):
 
     def on_round(self, params, round_index: int, step: int, topo: Topology,
                  active: np.ndarray) -> np.ndarray:
+        """One label round, as an ``idkd.round`` span; its compile-path
+        counts (:mod:`repro.obs.compile_path`) join
+        ``last_round_stats``."""
+        with span("idkd.round", round=int(round_index),
+                  nodes=int(topo.n)), CompileWatch() as watch:
+            per_node = self._label_round(params, round_index, step, topo,
+                                         active)
+        self.last_round_stats.update(watch.stats)
+        return per_node
+
+    def _label_round(self, params, round_index: int, step: int,
+                     topo: Topology, active: np.ndarray) -> np.ndarray:
         sim = self.sim
         cfg = self.idkd_cfg
         hom = sim._homogenize(params, cfg, topo,

@@ -68,6 +68,8 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import consensus_params, stack_params
 from repro.models import build_model
 from repro.obs import log as obs_log
+from repro.obs.compile_path import CompileWatch
+from repro.obs.trace import span
 from repro.resil import SimulatedCrash
 
 
@@ -121,9 +123,13 @@ def idkd_label_round(model, params_stacked, public_tokens, private_tokens,
     so the (n, P, S, V) public logit stack — the dominant HBM cost of a
     round at LLM vocab — never materializes. ``stream_labels=False``
     keeps the one-shot oracle path.
+
+    The uploads of the tokens are an ``idkd.inputs`` span
+    (:func:`repro.obs.trace.span`).
     """
-    pub = jnp.asarray(public_tokens)
-    priv = jnp.asarray(private_tokens)                      # (n, Vp, S)
+    with span("idkd.inputs"):
+        pub = jnp.asarray(public_tokens)
+        priv = jnp.asarray(private_tokens)                  # (n, Vp, S)
     # multi-codebook heads (MusicGen) have no single (d, V) unembedding
     # for head_select to tile — they keep the one-shot path
     streamable = getattr(model.cfg, "num_codebooks", 0) <= 1
@@ -227,11 +233,29 @@ class _LMFederation(sched.CompiledFederationHooks):
 
     def on_round(self, params, round_index: int, step: int, topo: Topology,
                  active: np.ndarray) -> np.ndarray:
+        """One label round, as an ``idkd.round`` span; its compile-path
+        counts (:mod:`repro.obs.compile_path`) join
+        ``last_round_stats``."""
+        with span("idkd.round", round=int(round_index),
+                  nodes=int(topo.n)), CompileWatch() as watch:
+            label_bytes = self._label_round(params, round_index, step,
+                                            topo, active)
+        self.last_round_stats.update(watch.stats)
+        return label_bytes
+
+    def _label_round(self, params, round_index: int, step: int,
+                     topo: Topology, active: np.ndarray) -> np.ndarray:
+        """The round's host phases, each an ``idkd.*`` span: ``inputs``
+        (the private sequences stacked and uploaded), the streaming
+        round's passes, threshold and exchange, ``readback`` (the KD
+        context, the masks and thresholds read back, the label bytes)
+        and ``topk_overlap``."""
         cfg = self.idkd_cfg
         n = self.tcfg.num_nodes
         m_priv = max(1, min(16, min(len(p) for p in self.parts)))
-        priv = np.stack([self.tokens[self.parts[i][:m_priv], :self.seq_len]
-                         for i in range(n)])
+        with span("idkd.inputs"):
+            priv = np.stack([self.tokens[self.parts[i][:m_priv],
+                                         :self.seq_len] for i in range(n)])
         backend = cfg.label_backend
         if backend not in ("fused", "sparse"):
             # the LM KD step consumes sparse payloads; the dense
@@ -244,32 +268,38 @@ class _LMFederation(sched.CompiledFederationHooks):
             backend=backend, active=None if active.all() else active,
             mesh=(self.shard_mesh(n) if self.driver_mode == "shard"
                   else None))
-        self.ctx = driver.lm_kd_ctx(sparse.values, sparse.indices, w)
+        with span("idkd.readback"):
+            self.ctx = driver.lm_kd_ctx(sparse.values, sparse.indices, w)
+            mask = np.asarray(id_mask)
+            thr = np.asarray(thr)
+            id_fraction = float(mask.mean())
+            counts = mask.sum(axis=1)
+            k_wire = min(cfg.label_topk or labeling.DEFAULT_TOPK,
+                         self.cfg.vocab_size)
+            label_bytes = np.array(
+                [distill.label_bytes(int(c) * self.seq_len,
+                                     self.cfg.vocab_size, k_wire)
+                 for c in counts], np.float64)
         if self.kd_sampler is None:
             self.kd_sampler = driver.make_lm_kd_sampler(
                 self.priv_parts, self.tokens, self.tcfg.batch_size,
                 self.public_tokens, sparse.values, sparse.indices, w,
                 pub_batch=min(4, len(self.public_tokens)))
         self.phase = "kd"
-        id_fraction = float(np.asarray(id_mask).mean())
-        counts = np.asarray(id_mask).sum(axis=1)
         if self.verbose:
             obs_log.info("idkd.round", step=step, round=round_index,
                          id_fraction=round(id_fraction, 4),
-                         thresholds=np.asarray(thr).round(3).tolist())
+                         thresholds=thr.round(3).tolist())
         # telemetry: run_schedule forwards this to on_labels + the
         # "labels" run-log event right after on_round returns
-        mean_ov, per_edge = labeling.neighbor_topk_overlap(
-            np.asarray(sparse.indices), topo)
+        with span("idkd.topk_overlap"):
+            mean_ov, per_edge = labeling.neighbor_topk_overlap(
+                np.asarray(sparse.indices), topo)
         self.last_round_stats = {
-            "thresholds": np.asarray(thr), "selected": counts,
+            "thresholds": thr, "selected": counts,
             "id_fraction": id_fraction, "detector": cfg.detector,
             "topk_overlap": mean_ov, "topk_overlap_per_edge": per_edge}
-        k_wire = min(cfg.label_topk or labeling.DEFAULT_TOPK,
-                     self.cfg.vocab_size)
-        return np.array([distill.label_bytes(int(c) * self.seq_len,
-                                             self.cfg.vocab_size, k_wire)
-                         for c in counts], np.float64)
+        return label_bytes
 
 
 def run_training(cfg: ModelConfig, tcfg: TrainConfig, *, seq_len: int = 64,

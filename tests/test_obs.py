@@ -90,14 +90,66 @@ def test_trace_spans_export_and_validate(tmp_path):
     with tr.span("outer", cat="test", idx=0):
         with tr.span("inner"):
             pass
-    tr.instant("mark")
     out = tmp_path / "trace.json"
     tr.export(out)
-    assert validate_trace(out) == 3
+    assert validate_trace(out) == 2
     doc = json.loads(out.read_text())
     durs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
     assert durs["outer"]["dur"] >= durs["inner"]["dur"]
     assert durs["outer"]["args"]["idx"] == 0
+
+
+def test_span_records_in_current_recorder_with_parent():
+    """The span helper records only into the current recorder, with the
+    enclosing span as parent; outside ``recording`` (or a tracing
+    Telemetry) it records nothing."""
+    from repro.obs.trace import current_recorder, recording, span
+    rec = TraceRecorder()
+    with span("idkd.lost"):
+        pass
+    with recording(rec):
+        assert current_recorder() is rec
+        with span("outer", cat="sched", step=1):
+            with span("idkd.inner", round=0):
+                pass
+    assert current_recorder() is None
+    ev = {e["name"]: e for e in rec.events}
+    assert set(ev) == {"outer", "idkd.inner"}
+    assert ev["idkd.inner"]["args"] == {"parent": "outer", "round": 0}
+    assert ev["idkd.inner"]["cat"] == "idkd"
+    assert ev["outer"]["args"] == {"step": 1}
+    # Telemetry(trace=True) holds its recorder current until close()
+    tel = Telemetry(None, trace=True)
+    with tel.span("segment", cat="train", start=0):
+        pass
+    assert current_recorder() is tel.tracer
+    tel.close()
+    assert current_recorder() is None
+    assert [e["name"] for e in tel.tracer.events] == ["segment"]
+
+
+def test_span_shares_the_profiler_clock(tmp_path):
+    """A recorded span and its xplane event start within 1 ms: the
+    recorder stamps with the wall clock the profiler's host events are
+    offsets of (``profile_start_time`` of the Task Environment plane)."""
+    from jax.profiler import ProfileData
+
+    from repro.obs.trace import recording, span
+    rec = TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with recording(rec), span("idkd.clock", round=7):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    start = {p.name: dict(p.stats) for p in pd.planes}[
+        "Task Environment"]["profile_start_time"]
+    found = [e for p in pd.planes for line in p.lines for e in line.events
+             if e.name == "idkd.clock"]
+    assert len(found) == 1 and dict(found[0].stats) == {"round": 7}
+    recorded_ns = rec.events[0]["ts"] * 1e3
+    assert abs(start + found[0].start_ns - recorded_ns) < 1e6
 
 
 def test_validate_trace_rejects_malformed(tmp_path):
@@ -277,6 +329,74 @@ def test_lm_trajectory_invariant_under_telemetry(tmp_path):
     lab = read_events(tmp_path / "run.jsonl", "labels")[0]
     assert len(lab["thresholds"]) == 2 and len(lab["selected"]) == 2
     assert 0.0 <= lab["topk_overlap"] <= 1.0
+    # the round's compile path rides the labels event, and the program's
+    # idkd.* spans nest under the scheduler's label_round span
+    from repro.obs.compile_path import KEYS
+    assert set(KEYS) <= set(lab) and lab["compiles"] > 0
+    spans = json.loads((tmp_path / "trace.json").read_text())["traceEvents"]
+    parents = {e["name"]: e["args"].get("parent") for e in spans
+               if e["name"].startswith("idkd.")}
+    assert parents["idkd.round"] == "label_round"
+    assert parents["idkd.public_pass"] == "idkd.round"
+
+
+def test_lm_round_spans_and_compile_counters():
+    """``on_round`` under a current recorder: the ``idkd.*`` phases nest
+    under ``idkd.round``, and ``last_round_stats`` carries the round's
+    compile path. A second round at the same shapes compiles only what
+    JAX's in-memory caches cannot hold (the streaming round's eager
+    scans are lowered anew on every call), with no persistent cache to
+    hit."""
+    from repro.configs import get_config
+    from repro.core.algorithms import make_algorithm
+    from repro.core.topology import Topology
+    from repro.launch.steps import stack_params
+    from repro.launch.train import _LMFederation
+    from repro.models import build_model
+    from repro.obs.compile_path import KEYS
+    from repro.obs.trace import recording
+
+    n, M, S, V = 2, 4, 8, 32
+    cfg = get_config("qwen1.5-0.5b").reduced().replace(
+        num_layers=1, d_model=16, num_heads=2, num_kv_heads=2, head_dim=8,
+        d_ff=32, vocab_size=V, dtype="float32")
+    model = build_model(cfg)
+    idkd = IDKDConfig(label_topk=4, label_backend="sparse")
+    tcfg = TrainConfig(num_nodes=n, steps=1, batch_size=2, idkd=idkd)
+    rng = np.random.default_rng(0)
+    fed = _LMFederation(
+        model=model, algo=make_algorithm("qg-dsgdm-n"), tcfg=tcfg,
+        idkd_cfg=idkd, cfg=cfg,
+        tokens=rng.integers(0, V, (n * M, S + 1), dtype=np.int32),
+        parts=[np.arange(i * M, (i + 1) * M) for i in range(n)],
+        public_tokens=rng.integers(0, V, (4, S), dtype=np.int32),
+        seq_len=S, wire_dtype="native", driver_mode="scan", verbose=False)
+    params = jax.jit(lambda key: stack_params(model.init(key), n))(
+        jax.random.PRNGKey(0))
+    topo, active = Topology.make("ring", n), np.ones(n, bool)
+    rec = TraceRecorder()
+    stats = []
+    with recording(rec):
+        for r in range(2):
+            fed.on_round(params, r, 0, topo, active)
+            stats.append(fed.last_round_stats)
+    rounds = [e for e in rec.events if e["name"] == "idkd.round"]
+    assert [e["args"] for e in rounds] == [
+        {"round": r, "nodes": n} for r in range(2)]
+    phases = {e["name"]: e["args"]["parent"] for e in rec.events
+              if e["name"] != "idkd.round"}
+    assert phases == {
+        "idkd.inputs": "idkd.round", "idkd.public_pass": "idkd.round",
+        "idkd.calibration_pass": "idkd.round",
+        "idkd.threshold": "idkd.round", "idkd.exchange": "idkd.round",
+        "idkd.readback": "idkd.round", "idkd.topk_overlap": "idkd.round"}
+    for st in stats:
+        assert set(KEYS) <= set(st)
+        assert st["compile_path_s"] == pytest.approx(
+            st["trace_s"] + st["lower_s"] + st["backend_compile_s"])
+        assert st["cache_hits"] == 0
+    first, second = stats
+    assert 0 < second["compiles"] < first["compiles"]
 
 
 # --------------------------------------------- acceptance scenario

@@ -18,6 +18,8 @@ end-to-end trajectory equality of the streaming vs one-shot rounds.
   node-stacked and shard drivers) with streaming rounds must match the
   ``stream_labels=False`` one-shot rounds to float tolerance.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -341,6 +343,44 @@ def test_shard_streaming_2d_mesh_matches_stacked(request, setup_name, C):
                                       np.asarray(ref.weights))
         np.testing.assert_allclose(np.asarray(out.densify(C)),
                                    np.asarray(ref.densify(C)), atol=1e-5)
+
+
+# ------------------------------------------------------- name scopes
+SCOPES = ("public_pass", "calibration_pass", "exchange")
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_round_phases_carry_their_name_scope(lm_setup, scope):
+    """Each phase's operations carry its ``jax.named_scope`` in the op
+    metadata of the lowered program, and no other phase's name."""
+    model, params, pub, val = lm_setup
+    cfg = IDKDConfig(label_topk=4, stream_microbatch=8)
+    topo = Topology.make("ring", N)
+    P = pub.shape[0]
+
+    def run_public(pr, pb):
+        chunks, _, _ = labeling._chunk_public(pb, cfg.stream_microbatch)
+        return labeling._stream_public(model, pr, chunks, P, cfg, 4)
+
+    def run_calibration(pr, vl):
+        return labeling._stream_val_conf(model, pr, vl, cfg)
+
+    def run_exchange(conf, vals, idx):
+        sparse = labeling.distill.SparseLabels(vals, idx)
+        return labeling.exchange_sparse(topo, conf > 0.5, sparse)
+
+    calls = {
+        "public_pass": (run_public, (params, pub)),
+        "calibration_pass": (run_calibration, (params, val)),
+        "exchange": (run_exchange, (jnp.zeros((N, P)),
+                                jnp.zeros((N, P, 6, 4)),
+                                jnp.zeros((N, P, 6, 4), jnp.int32))),
+    }
+    fn, args = calls[scope]
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    named = {part for loc in re.findall(r'loc\("([^"]*)"', text)
+             for part in loc.split("/")}
+    assert named & set(SCOPES) == {scope}
 
 
 # --------------------------------------------------------- jaxpr audit
