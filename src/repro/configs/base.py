@@ -349,9 +349,12 @@ class IDKDConfig:
     stream_microbatch: int = 256    # public samples per streaming chunk
                                     # (the simulator's pre-streaming host
                                     # batching used the same 256)
-    select_block_rows: int = 8      # row-block of the msp_select /
-                                    # head_select kernels (8 rows × 257k
-                                    # vocab ≈ 8 MB VMEM in f32)
+    select_block_rows: int = 8      # row granule of the msp_select /
+                                    # head_select kernels: msp_select's
+                                    # row block; head_select sizes its
+                                    # row tile (rows per read of the
+                                    # head) from the shapes, as a
+                                    # multiple of this
 
 
 @dataclass(frozen=True)

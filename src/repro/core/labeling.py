@@ -54,6 +54,7 @@ the node axis.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Tuple, Union
 
 import jax
@@ -62,7 +63,8 @@ import jax.numpy as jnp
 from repro.configs.base import IDKDConfig
 from repro.core import distill, ood
 from repro.core.topology import Topology
-from repro.kernels.head_select import (NEG_INF, head_select, head_select_ref,
+from repro.kernels.head_select import (BLOCK_C, NEG_INF, head_row_tile,
+                                       head_select, head_select_ref,
                                        head_select_stats_ref,
                                        merge_head_stats)
 from repro.kernels.msp_select import msp_select, msp_select_ref
@@ -235,15 +237,9 @@ def _head_pass(model, params_i, x, cfg: IDKDConfig, k: int):
     lead = feats.shape[:-1]                                # (mb,) or (mb, S)
     flat = feats.reshape(-1, feats.shape[-1])
     if jax.default_backend() == "tpu":
-        block = cfg.select_block_rows
-        pad = (-flat.shape[0]) % block
-        n_rows = flat.shape[0]
-        if pad:
-            flat = jnp.pad(flat, ((0, pad), (0, 0)))
         conf, vals, idx = head_select(
             flat, w, b, temperature=cfg.temperature, k=k,
-            block_rows=block, detector=cfg.detector)
-        conf, vals, idx = conf[:n_rows], vals[:n_rows], idx[:n_rows]
+            block_rows=cfg.select_block_rows, detector=cfg.detector)
     else:
         conf, vals, idx = _stream_oracle(
             flat, w, b, temperature=cfg.temperature, k=k,
@@ -291,16 +287,10 @@ def _vocab_sharded_head_pass(model, params_i, x, cfg: IDKDConfig, k: int,
     lead = feats.shape[:-1]                                # (mb,) or (mb, S)
     flat = feats.reshape(-1, feats.shape[-1])
     if jax.default_backend() == "tpu":
-        block = cfg.select_block_rows
-        pad = (-flat.shape[0]) % block
-        n_rows = flat.shape[0]
-        if pad:
-            flat = jnp.pad(flat, ((0, pad), (0, 0)))
         ms, zs, tv, ti = head_select(
             flat, w_loc, b_loc, temperature=cfg.temperature, k=k_loc,
-            block_rows=block, detector=cfg.detector, raw_stats=True)
-        ms, zs = ms[:n_rows], zs[:n_rows]
-        tv, ti = tv[:n_rows], ti[:n_rows]
+            block_rows=cfg.select_block_rows, detector=cfg.detector,
+            raw_stats=True)
     else:
         ms, zs, tv, ti = head_select_stats_ref(flat, w_loc, b_loc, k=k_loc)
     ti = ti + j * w_sh                                     # global vocab idx
@@ -324,18 +314,55 @@ def _head_width(model, params) -> int:
     return jax.eval_shape(lambda p: model.head_params(p)[0], one).shape[-1]
 
 
+def _microbatch(P: int, microbatch: int) -> int:
+    return max(1, min(microbatch or 256, P))
+
+
 def _chunk_public(public_x, microbatch: int):
     """(P, ...) -> ((num_chunks, mb, ...), P, mb). The ragged tail is
     padded by repeating row 0 (real inputs, outputs sliced off)."""
     pub = jnp.asarray(public_x)
     P = pub.shape[0]
-    mb = max(1, min(microbatch or 256, P))
+    mb = _microbatch(P, microbatch)
     num_chunks = -(-P // mb)
     pad = num_chunks * mb - P
     if pad:
         pub = jnp.concatenate(
             [pub, jnp.broadcast_to(pub[:1], (pad,) + pub.shape[1:])])
     return pub.reshape((num_chunks, mb) + pub.shape[1:]), P, mb
+
+
+def head_reads(model, params, public_x, val_x, cfg: IDKDConfig, *,
+               model_size: int = 1) -> int:
+    """Whole-head reads of one streaming round on TPU, summed over the
+    nodes and both passes (public and calibration, as the round runs
+    with ``filter_ood``): the row tiles ``head_select`` takes in each
+    call (:func:`repro.kernels.head_select.head_row_tile`), from shapes
+    alone (``eval_shape`` of one node's features and head). On the
+    vocab-sharded mesh (``model_size > 1``) a row tile reads each
+    shard's slice once, one whole head between them. Off TPU the jnp
+    oracle runs instead; the count is that of the same shapes."""
+    n = jax.tree.leaves(params)[0].shape[0]
+    one = jax.tree.map(
+        lambda t: jax.ShapeDtypeStruct(t.shape[1:], t.dtype), params)
+
+    def tiles(x_shape, x_dtype):
+        feats, w = jax.eval_shape(
+            lambda p, x: (model.forward_features(
+                p, {model.input_key: x})[0], model.head_params(p)[0]),
+            one, jax.ShapeDtypeStruct(
+                x_shape, jax.dtypes.canonicalize_dtype(x_dtype)))
+        rows = math.prod(feats.shape[:-1])
+        block_c = min(BLOCK_C, -(-w.shape[-1] // model_size))
+        tile = head_row_tile(rows, feats.shape[-1], block_c,
+                             cfg.select_block_rows, feats.dtype, w.dtype)
+        return -(-rows // tile)
+
+    P = public_x.shape[0]
+    mb = _microbatch(P, cfg.stream_microbatch)
+    return n * (-(-P // mb) * tiles((mb,) + public_x.shape[1:],
+                                    public_x.dtype)
+                + tiles(val_x.shape[1:], val_x.dtype))
 
 
 def _stream_public(model, params, chunks, P: int, cfg: IDKDConfig, k: int,

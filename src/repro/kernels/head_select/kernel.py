@@ -8,8 +8,8 @@ confidence and the renormalized top-k sparse soft label — with the
 **vocab axis tiled**: the full ``(rows, C)`` logit tensor never exists
 in any memory.
 
-Per ``(row_block, vocab_block)`` grid cell the kernel does one MXU
-matmul ``hidden @ W[:, c0:c1]`` in VMEM and folds the block into
+Per ``(row_tile, vocab_block)`` grid cell the kernel does one MXU
+matmul ``hidden[r0:r1] @ W[:, c0:c1]`` in VMEM and folds the block into
 running per-row state (the same scratch-accumulator pattern as the
 in-repo flash_attention kernel, whose online-softmax (m, l) carry this
 reuses):
@@ -31,11 +31,14 @@ every reduction keeps its axis, so no rank-1 block, gather, stack or
 concatenate reaches the TPU compiler; argmax is a masked min over the
 column iota, and reading the carry's index at a slot is a masked max.
 
-VMEM per cell: ``block_rows × D`` hidden + ``D × block_c`` weights +
-``block_rows × block_c`` scores (f32). At D=4k, block_c=512,
-block_rows=8 that is ≈ 9 MB — comfortably resident; HBM traffic is one
-read of W per row block and one read of the hidden states, with
-O(rows · k) outputs instead of O(rows · C).
+Row tile. HBM traffic is one read of W per row tile and one read of the
+hidden states, with O(rows · k) outputs instead of O(rows · C). The
+rows per head read are therefore sized from the shapes, not set by the
+caller: :func:`head_row_tile` takes the largest tile whose VMEM
+footprint (:func:`head_vmem_bytes`) fits :data:`VMEM_BUDGET` — 256
+rows for qwen3-1.7b's (2048, 151,936) bf16 head, one read of W per 256
+rows (the caller's ``block_rows`` is the granule the tile is a
+multiple of). Every row's arithmetic is the same whatever the tile.
 """
 from __future__ import annotations
 
@@ -47,6 +50,45 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+BLOCK_C = 512            # vocab columns per grid cell
+# scoped VMEM a head_select grid cell is sized against: three quarters
+# of the 16 MiB a v5e kernel gets by default, the rest left to Mosaic's
+# own temporaries
+VMEM_BUDGET = 12 * 2**20
+_LANES = 128             # a (rows, 1) or (rows, k) column pads to this
+
+
+def head_vmem_bytes(rows: int, dim: int, block_c: int, hidden_dtype,
+                    head_dtype) -> int:
+    """Scoped VMEM of one ``head_select`` grid cell at a row tile of
+    ``rows``: the double-buffered hidden tile, head tile and bias row,
+    the f32 score tile and the fold's working copy of it, the
+    ``(m, z, tv, ti)`` carry and up to four double-buffered per-row
+    outputs, each lane-padded."""
+    h = jnp.dtype(hidden_dtype).itemsize
+    w = jnp.dtype(head_dtype).itemsize
+    return (2 * rows * dim * h                 # hidden tile
+            + 2 * dim * block_c * w            # head tile
+            + 2 * 8 * block_c * 4              # bias row, one f32 tile
+            + 2 * rows * block_c * 4           # scores + working copy
+            + 4 * rows * _LANES * 4            # carry
+            + 2 * 4 * rows * _LANES * 4)       # outputs
+
+
+def head_row_tile(rows: int, dim: int, block_c: int, granule: int,
+                  hidden_dtype, head_dtype) -> int:
+    """Rows per head read for ``rows`` hidden states against a ``(dim,
+    C)`` head in ``block_c``-column blocks: the largest ``granule · 2^j``
+    whose :func:`head_vmem_bytes` fits :data:`VMEM_BUDGET` (the granule
+    itself where none does), no larger than the rows need, then evened
+    out over the tiles it implies so padding stays under one granule a
+    tile. Always a multiple of ``granule``."""
+    tile = granule
+    while tile < rows and head_vmem_bytes(
+            2 * tile, dim, block_c, hidden_dtype, head_dtype) <= VMEM_BUDGET:
+        tile *= 2
+    tiles = -(-rows // tile)
+    return -(-rows // (tiles * granule)) * granule
 
 
 def _init_carry(m_scr, z_scr, tv_scr, ti_scr):
@@ -138,14 +180,15 @@ def _select_kernel(*refs, scores, num_inputs: int, temperature: float,
 
 
 def select_call(scores, inputs, in_specs, *, rows: int, num_classes: int,
-                block_rows: int, block_c: int, k: int, temperature: float,
+                row_tile: int, block_c: int, k: int, temperature: float,
                 detector: str, raw_stats: bool, interpret: bool):
     """The ``(row, vocab)``-grid pallas_call shared by ``head_select``
     and ``msp_select``: ``scores(*in_refs)`` yields each cell's
-    ``(block_rows, block_c)`` f32 logits, folded into the carry.
+    ``(row_tile, block_c)`` f32 logits, folded into the carry.
 
-    A ragged last vocab block reads past ``num_classes``; the fold masks
-    those columns, so no input is ever padded. Per-row outputs are
+    ``rows`` is a multiple of ``row_tile``. A ragged last vocab block
+    reads past ``num_classes``; the fold masks those columns, so no
+    input is padded along the vocab. Per-row outputs are
     ``(rows, 1)`` columns in the kernel (Mosaic takes no rank-1 block
     smaller than 128) and come back as ``(rows,)``."""
     num_c_blocks = pl.cdiv(num_classes, block_c)
@@ -154,22 +197,22 @@ def select_call(scores, inputs, in_specs, *, rows: int, num_classes: int,
         temperature=temperature, k=k, detector=detector, block_c=block_c,
         num_c_blocks=num_c_blocks, num_classes=num_classes,
         raw_stats=raw_stats)
-    col_spec = pl.BlockSpec((block_rows, 1), lambda i, c: (i, 0))
-    topk_spec = pl.BlockSpec((block_rows, k), lambda i, c: (i, 0))
+    col_spec = pl.BlockSpec((row_tile, 1), lambda i, c: (i, 0))
+    topk_spec = pl.BlockSpec((row_tile, k), lambda i, c: (i, 0))
     col = jax.ShapeDtypeStruct((rows, 1), jnp.float32)
     tv = jax.ShapeDtypeStruct((rows, k), jnp.float32)
     ti = jax.ShapeDtypeStruct((rows, k), jnp.int32)
     n_cols = 2 if raw_stats else 1
     outs = pl.pallas_call(
         kernel,
-        grid=(rows // block_rows, num_c_blocks),
+        grid=(rows // row_tile, num_c_blocks),
         in_specs=in_specs,
         out_specs=(col_spec,) * n_cols + (topk_spec, topk_spec),
         out_shape=(col,) * n_cols + (tv, ti),
-        scratch_shapes=[pltpu.VMEM((block_rows, 1), jnp.float32),
-                        pltpu.VMEM((block_rows, 1), jnp.float32),
-                        pltpu.VMEM((block_rows, k), jnp.float32),
-                        pltpu.VMEM((block_rows, k), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((row_tile, 1), jnp.float32),
+                        pltpu.VMEM((row_tile, 1), jnp.float32),
+                        pltpu.VMEM((row_tile, k), jnp.float32),
+                        pltpu.VMEM((row_tile, k), jnp.int32)],
         interpret=interpret,
     )(*inputs)
     return tuple(o[:, 0] for o in outs[:n_cols]) + tuple(outs[n_cols:])
@@ -186,11 +229,16 @@ def _head_scores(h_ref, w_ref, b_ref):
 
 
 def head_select_pallas(hidden, w, bias, *, temperature: float, k: int = 8,
-                       block_rows: int = 8, block_c: int = 512,
+                       block_rows: int = 8, block_c: int = BLOCK_C,
                        interpret: bool = True, detector: str = "msp",
                        raw_stats: bool = False):
     """hidden (N, D) + head (D, C) [+ bias (C,)] ->
     (conf (N,), vals (N, k), idx (N, k)) with the vocab axis tiled.
+
+    The rows go through in tiles of :func:`head_row_tile` rows, a
+    multiple of ``block_rows``, each tile reading the head once; ``N``
+    is padded here to a whole number of tiles and the outputs sliced
+    back.
 
     ``raw_stats=True`` returns the pre-finalizer carry
     ``(m (N,), z (N,), tv (N, k), ti (N, k))`` instead — the per-shard
@@ -201,16 +249,20 @@ def head_select_pallas(hidden, w, bias, *, temperature: float, k: int = 8,
     assert w.shape[0] == D, (w.shape, hidden.shape)
     assert k <= C, "clamp k to the class count before calling"
     assert detector in ("msp", "energy"), detector
-    block_rows = min(block_rows, N)
-    assert N % block_rows == 0, "pad rows to a block multiple"
     block_c = min(block_c, C)
+    row_tile = head_row_tile(N, D, block_c, block_rows, hidden.dtype,
+                             w.dtype)
+    rows = -(-N // row_tile) * row_tile
+    if rows != N:
+        hidden = jnp.pad(hidden, ((0, rows - N), (0, 0)))
     if bias is None:
         bias = jnp.zeros((C,), jnp.float32)
-    in_specs = [pl.BlockSpec((block_rows, D), lambda i, c: (i, 0)),
+    in_specs = [pl.BlockSpec((row_tile, D), lambda i, c: (i, 0)),
                 pl.BlockSpec((D, block_c), lambda i, c: (0, c)),
                 pl.BlockSpec((1, block_c), lambda i, c: (0, c))]
-    return select_call(_head_scores, (hidden, w, bias.reshape(1, -1)),
-                       in_specs, rows=N, num_classes=C,
-                       block_rows=block_rows, block_c=block_c, k=k,
+    outs = select_call(_head_scores, (hidden, w, bias.reshape(1, -1)),
+                       in_specs, rows=rows, num_classes=C,
+                       row_tile=row_tile, block_c=block_c, k=k,
                        temperature=temperature, detector=detector,
                        raw_stats=raw_stats, interpret=interpret)
+    return tuple(o[:N] for o in outs) if rows != N else outs

@@ -5,7 +5,7 @@ import functools
 
 import jax
 
-from repro.kernels.head_select.kernel import head_select_pallas
+from repro.kernels.head_select.kernel import BLOCK_C, head_select_pallas
 from repro.kernels.head_select.ref import (head_select_ref,
                                            head_select_stats_ref,
                                            merge_head_stats)
@@ -16,7 +16,7 @@ from repro.kernels.head_select.ref import (head_select_ref,
                                              "interpret", "detector",
                                              "raw_stats"))
 def head_select(hidden, w, bias=None, *, temperature: float = 10.0,
-                k: int = 8, block_rows: int = 8, block_c: int = 512,
+                k: int = 8, block_rows: int = 8, block_c: int = BLOCK_C,
                 interpret: bool | None = None, detector: str = "msp",
                 raw_stats: bool = False):
     if interpret is None:

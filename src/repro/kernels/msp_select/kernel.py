@@ -47,6 +47,6 @@ def msp_select_pallas(logits, *, temperature: float, k: int = 8,
     block_c = min(BLOCK_C, C)
     in_specs = [pl.BlockSpec((block_n, block_c), lambda i, c: (i, c))]
     return select_call(_logit_scores, (logits,), in_specs, rows=N,
-                       num_classes=C, block_rows=block_n, block_c=block_c,
+                       num_classes=C, row_tile=block_n, block_c=block_c,
                        k=k, temperature=temperature, detector=detector,
                        raw_stats=False, interpret=interpret)

@@ -100,6 +100,14 @@ def make_gossip_mixer(tcfg: TrainConfig, wire_dtype: str = "native",
                             wire_fault=wire_fault, wire_guard=wire_guard)
 
 
+def _streams(model, idkd_cfg: IDKDConfig, backend: str) -> bool:
+    """Whether :func:`idkd_label_round` takes the streaming path.
+    Multi-codebook heads (MusicGen) have no single (d, V) unembedding
+    for head_select to tile — they keep the one-shot path."""
+    return (idkd_cfg.stream_labels and backend in ("fused", "sparse")
+            and getattr(model.cfg, "num_codebooks", 0) <= 1)
+
+
 def idkd_label_round(model, params_stacked, public_tokens, private_tokens,
                      idkd_cfg: IDKDConfig, topology: Topology,
                      backend: str = "sparse", active=None, mesh=None):
@@ -130,11 +138,7 @@ def idkd_label_round(model, params_stacked, public_tokens, private_tokens,
     with span("idkd.inputs"):
         pub = jnp.asarray(public_tokens)
         priv = jnp.asarray(private_tokens)                  # (n, Vp, S)
-    # multi-codebook heads (MusicGen) have no single (d, V) unembedding
-    # for head_select to tile — they keep the one-shot path
-    streamable = getattr(model.cfg, "num_codebooks", 0) <= 1
-    if idkd_cfg.stream_labels and streamable \
-            and backend in ("fused", "sparse"):
+    if _streams(model, idkd_cfg, backend):
         if mesh is not None:
             if active is not None:
                 raise ValueError("sharded label rounds have no churn "
@@ -204,6 +208,7 @@ class _LMFederation(sched.CompiledFederationHooks):
         # compressed-wire spec ((kind, frac) or None) read off the config;
         # self.gossip is overwritten from the schedule by init_comm
         self.compression = tcfg.compression_spec
+        self.head_reads = None      # per streaming round, from shapes
 
     def _make_mixer(self, topo: Topology, active, stale=None):
         return make_gossip_mixer(self.tcfg, self.wire_dtype,
@@ -299,6 +304,13 @@ class _LMFederation(sched.CompiledFederationHooks):
             "thresholds": thr, "selected": counts,
             "id_fraction": id_fraction, "detector": cfg.detector,
             "topk_overlap": mean_ov, "topk_overlap_per_edge": per_edge}
+        if _streams(self.model, cfg, backend):
+            if self.head_reads is None:
+                self.head_reads = labeling.head_reads(
+                    self.model, params, self.public_tokens, priv, cfg,
+                    model_size=(self.model_parallel
+                                if self.driver_mode == "shard" else 1))
+            self.last_round_stats["head_reads"] = self.head_reads
         return label_bytes
 
 

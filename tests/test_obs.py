@@ -318,6 +318,17 @@ def _lm_run(telemetry=None):
     return out["loss_history"]
 
 
+def _tiny_head_reads(*, n, rows_pub, rows_cal, d, vocab, granule=8):
+    """Whole-head reads of one streaming round of a tiny f32 LM whose
+    public set fits one microbatch: each node's public and calibration
+    pass take ``cdiv(rows, tile)`` row tiles, one head read each."""
+    from repro.kernels.head_select import BLOCK_C, head_row_tile
+    return n * sum(
+        -(-rows // head_row_tile(rows, d, min(BLOCK_C, vocab), granule,
+                                 np.float32, np.float32))
+        for rows in (rows_pub, rows_cal))
+
+
 def test_lm_trajectory_invariant_under_telemetry(tmp_path):
     off = _lm_run()
     tel = Telemetry(tmp_path, trace=True)
@@ -333,6 +344,8 @@ def test_lm_trajectory_invariant_under_telemetry(tmp_path):
     # idkd.* spans nest under the scheduler's label_round span
     from repro.obs.compile_path import KEYS
     assert set(KEYS) <= set(lab) and lab["compiles"] > 0
+    assert lab["head_reads"] == _tiny_head_reads(
+        n=2, rows_pub=8 * 16, rows_cal=16 * 16, d=64, vocab=128)
     spans = json.loads((tmp_path / "trace.json").read_text())["traceEvents"]
     parents = {e["name"]: e["args"].get("parent") for e in spans
                if e["name"].startswith("idkd.")}
@@ -340,21 +353,17 @@ def test_lm_trajectory_invariant_under_telemetry(tmp_path):
     assert parents["idkd.public_pass"] == "idkd.round"
 
 
-def test_lm_round_spans_and_compile_counters():
-    """``on_round`` under a current recorder: the ``idkd.*`` phases nest
-    under ``idkd.round``, and ``last_round_stats`` carries the round's
-    compile path. A second round at the same shapes compiles only what
-    JAX's in-memory caches cannot hold (the streaming round's eager
-    scans are lowered anew on every call), with no persistent cache to
-    hit."""
+def _tiny_lm_federation():
+    """The LM hooks at a tiny size: 2 nodes on a ring, 4 private and 4
+    public sequences of 8 tokens, a 1-layer f32 model with d_model 16
+    and a 32-token vocabulary. Returns (hooks, params, topology,
+    active)."""
     from repro.configs import get_config
     from repro.core.algorithms import make_algorithm
     from repro.core.topology import Topology
     from repro.launch.steps import stack_params
     from repro.launch.train import _LMFederation
     from repro.models import build_model
-    from repro.obs.compile_path import KEYS
-    from repro.obs.trace import recording
 
     n, M, S, V = 2, 4, 8, 32
     cfg = get_config("qwen1.5-0.5b").reduced().replace(
@@ -373,7 +382,21 @@ def test_lm_round_spans_and_compile_counters():
         seq_len=S, wire_dtype="native", driver_mode="scan", verbose=False)
     params = jax.jit(lambda key: stack_params(model.init(key), n))(
         jax.random.PRNGKey(0))
-    topo, active = Topology.make("ring", n), np.ones(n, bool)
+    return fed, params, Topology.make("ring", n), np.ones(n, bool)
+
+
+def test_lm_round_spans_and_compile_counters():
+    """``on_round`` under a current recorder: the ``idkd.*`` phases nest
+    under ``idkd.round``, and ``last_round_stats`` carries the round's
+    compile path. A second round at the same shapes compiles only what
+    JAX's in-memory caches cannot hold (the streaming round's eager
+    scans are lowered anew on every call), with no persistent cache to
+    hit."""
+    from repro.obs.compile_path import KEYS
+    from repro.obs.trace import recording
+
+    fed, params, topo, active = _tiny_lm_federation()
+    n = topo.n
     rec = TraceRecorder()
     stats = []
     with recording(rec):
@@ -397,6 +420,22 @@ def test_lm_round_spans_and_compile_counters():
         assert st["cache_hits"] == 0
     first, second = stats
     assert 0 < second["compiles"] < first["compiles"]
+
+
+def test_lm_round_counts_head_reads_from_shapes(monkeypatch):
+    """``last_round_stats["head_reads"]``: whole-head reads of the
+    round's ``head_select`` calls, summed over both nodes and both
+    passes, from the kernel's own row-tile chooser. Under a scoped-VMEM
+    budget of one 8-row granule, each node's 32 public and 32
+    calibration rows take 4 row tiles each."""
+    from repro.kernels.head_select import kernel
+    monkeypatch.setattr(kernel, "VMEM_BUDGET", kernel.head_vmem_bytes(
+        8, 16, 32, np.float32, np.float32))
+    fed, params, topo, active = _tiny_lm_federation()
+    fed.on_round(params, 0, 0, topo, active)
+    want = _tiny_head_reads(n=2, rows_pub=4 * 8, rows_cal=4 * 8, d=16,
+                            vocab=32)
+    assert fed.last_round_stats["head_reads"] == want == 2 * (4 + 4)
 
 
 # --------------------------------------------- acceptance scenario

@@ -186,6 +186,73 @@ def test_head_select_property(scale, T, k):
     np.testing.assert_allclose(v.sum(-1), 1.0, atol=1e-4)
 
 
+# ------------------------------------------- head_select row tile
+def _head_select_at(monkeypatch, h, w, b, *, budget, **kw):
+    """``head_select_pallas`` (interpret mode) with its row tile sized
+    against ``budget`` bytes of scoped VMEM."""
+    from repro.kernels.head_select import kernel
+    monkeypatch.setattr(kernel, "VMEM_BUDGET", budget)
+    return kernel.head_select_pallas(h, w, b, temperature=5.0,
+                                     interpret=True, **kw)
+
+
+@pytest.mark.parametrize("N,D,C,bc,k,det,raw,tile,dtype", [
+    (20, 32, 200, 64, 8, "msp", False, None, "float32"),    # N < R
+    (40, 64, 300, 128, 1, "energy", True, 16, "bfloat16"),  # N % R != 0
+    (100, 32, 1000, 128, 8, "energy", False, 32, "bfloat16"),
+    (64, 16, 256, 64, 1, "msp", True, None, "float32"),     # C % bc == 0
+    (44, 32, 90, 64, 8, "msp", True, 16, "float32"),
+])
+def test_head_select_row_tile_bitwise_equals_granule_tile(
+        monkeypatch, N, D, C, bc, k, det, raw, tile, dtype):
+    """Rows per head read sized from the shapes (``head_row_tile``; a
+    ``tile`` sizes the budget so the chooser lands on it) give bitwise
+    the outputs of one ``block_rows`` granule per head read: each row's
+    scores, carry and finalizer are the same arithmetic whatever the
+    tile, with rows padded to whole tiles and sliced back."""
+    from repro.kernels.head_select import kernel
+    rng = np.random.default_rng(N + C)
+    h = jnp.asarray(rng.normal(size=(N, D)), dtype)
+    w = jnp.asarray(rng.normal(size=(D, C)) * 0.3, dtype)
+    b = jnp.asarray(rng.normal(size=(C,)), jnp.float32)
+    kw = dict(k=k, block_rows=8, block_c=bc, detector=det, raw_stats=raw)
+    budget = (kernel.VMEM_BUDGET if tile is None else
+              kernel.head_vmem_bytes(tile, D, bc, h.dtype, w.dtype))
+    monkeypatch.setattr(kernel, "VMEM_BUDGET", budget)
+    assert kernel.head_row_tile(N, D, bc, 8, h.dtype, w.dtype) == (
+        tile or -(-N // 8) * 8)
+    shaped = _head_select_at(monkeypatch, h, w, b, budget=budget, **kw)
+    granule = _head_select_at(monkeypatch, h, w, b, budget=0, **kw)
+    assert len(shaped) == (4 if raw else 3)
+    for got, want in zip(shaped, granule):
+        assert got.shape == want.shape and got.shape[0] == N
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("rows,D,bc,granule,dtype,want", [
+    (8192, 2048, 512, 8, "bfloat16", 256),     # qwen3-1.7b public pass
+    (2048, 2048, 512, 8, "bfloat16", 256),     # its calibration pass
+    (8192, 4096, 512, 8, "bfloat16", 128),
+    (300, 2048, 512, 8, "bfloat16", 152),      # 2 tiles, evened out
+    (256, 64, 10, 8, "float32", 256),          # resnet head: one tile
+    (4, 64, 10, 8, "float32", 8),              # fewer rows than a granule
+    (8192, 65536, 512, 8, "float32", 8),       # nothing fits: the granule
+])
+def test_head_row_tile_fits_vmem_budget(rows, D, bc, granule, dtype, want):
+    """The row tile is a multiple of the granule, fits the scoped-VMEM
+    budget by its own footprint formula wherever more than a granule
+    does, and is the largest power-of-two multiple that does (evened out
+    over the tiles it implies)."""
+    from repro.kernels.head_select import kernel
+    tile = kernel.head_row_tile(rows, D, bc, granule, dtype, dtype)
+    assert tile == want and tile % granule == 0
+    if tile > granule:
+        assert kernel.head_vmem_bytes(tile, D, bc, dtype, dtype) \
+            <= kernel.VMEM_BUDGET
+    assert 2 * tile >= rows or kernel.head_vmem_bytes(
+        2 * tile, D, bc, dtype, dtype) > kernel.VMEM_BUDGET
+
+
 # ------------------------------------------------- fixtures (tiny models)
 @pytest.fixture(scope="module")
 def cls_setup():

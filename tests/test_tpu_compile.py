@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.head_select import head_select
+from repro.kernels.head_select import BLOCK_C, head_row_tile, head_select
 from repro.kernels.msp_select import msp_select
 
 QWEN3_D, QWEN3_VOCAB = 2048, 151_936        # qwen3-1.7b published widths
@@ -65,6 +65,23 @@ def test_head_select_compiles_at_qwen3_widths(one_chip, raw_stats, C):
         lambda h, w: head_select(h, w, temperature=10.0, k=8,
                                  interpret=False, raw_stats=raw_stats),
         h, w)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,k", [(8192, 8), (2048, 1)])
+def test_head_select_compiles_at_label_round_shapes(one_chip, rows, k):
+    """The qwen3-1.7b label round's two passes as the round runs them,
+    two nodes under ``vmap``: 64 × 128 public positions with top-8 and
+    16 × 128 calibration positions with top-1 against the full bf16
+    head, at the row tile the kernel sizes from these shapes (256 rows
+    per head read; 512 overflows the scoped VMEM)."""
+    assert head_row_tile(rows, QWEN3_D, BLOCK_C, 8, jnp.bfloat16,
+                         jnp.bfloat16) == 256
+    h = _spec((2, rows, QWEN3_D), jnp.bfloat16, one_chip)
+    w = _spec((2, QWEN3_D, QWEN3_VOCAB), jnp.bfloat16, one_chip)
+    text = _compiled_text(
+        jax.vmap(lambda h, w: head_select(h, w, temperature=10.0, k=k,
+                                          interpret=False)), h, w)
     assert "tpu_custom_call" in text
 
 
