@@ -4,9 +4,9 @@ from __future__ import annotations
 from repro.configs.base import (IDKDConfig, MLAConfig, ModelConfig, MoEConfig,
                                 SHAPES, ShapeConfig, SSMConfig, TrainConfig)
 from repro.configs import (arctic_480b, deepseek_v3_671b, hymba_1_5b,
-                           mamba2_780m, mistral_nemo_12b, musicgen_medium,
-                           paligemma_3b, phi3_mini_3_8b, qwen1_5_0_5b,
-                           qwen3_1_7b, resnet20_cifar)
+                           mamba2_780m, mistral_nemo_12b, moonlight_16b_a3b,
+                           musicgen_medium, paligemma_3b, phi3_mini_3_8b,
+                           qwen1_5_0_5b, qwen3_1_7b, resnet20_cifar)
 
 ARCHS = {
     "mamba2-780m": mamba2_780m.CONFIG,
@@ -15,6 +15,7 @@ ARCHS = {
     "paligemma-3b": paligemma_3b.CONFIG,
     "arctic-480b": arctic_480b.CONFIG,
     "deepseek-v3-671b": deepseek_v3_671b.CONFIG,
+    "moonlight-16b-a3b": moonlight_16b_a3b.CONFIG,
     "mistral-nemo-12b": mistral_nemo_12b.CONFIG,
     "phi3-mini-3.8b": phi3_mini_3_8b.CONFIG,
     "qwen1.5-0.5b": qwen1_5_0_5b.CONFIG,

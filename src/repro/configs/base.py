@@ -32,6 +32,21 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_type: str = "softmax"        # "softmax" | "sigmoid" (DeepSeek-v3)
     router_aux_coef: float = 0.01       # load-balance aux loss weight
+    router_f32: bool = False            # router logits from float32
+                                        # operands at 'highest' precision
+                                        # (the DeepSeek-V3 gate)
+    routed_scaling_factor: float = 1.0  # scales the normalized top-k
+                                        # weights (DeepSeek-V3: 2.5,
+                                        # Moonlight: 2.446)
+    held_experts: Optional[Tuple[int, int]] = None
+                                        # (first, count): the layer holds
+                                        # routed experts [first, first +
+                                        # count) of num_experts, routes over
+                                        # all of them and computes, dropless,
+                                        # only its own experts' part (expert
+                                        # parallelism's share of one chip).
+                                        # None: it holds every expert and
+                                        # drops past capacity_factor
     dispatch_groups: int = 1            # §Perf: GShard-style local dispatch
                                         # groups (= data shards). A global
                                         # argsort is unshardable — GSPMD
@@ -42,6 +57,11 @@ class MoEConfig:
     @property
     def enabled(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this layer holds."""
+        return self.held_experts or (0, self.num_experts)
 
 
 @dataclass(frozen=True)
@@ -189,6 +209,8 @@ class ModelConfig:
                 num_shared_experts=min(moe.num_shared_experts, 1),
                 dense_residual_ff=min(moe.dense_residual_ff, 128),
                 first_k_dense=min(moe.first_k_dense, 1),
+                held_experts=(None if moe.held_experts is None
+                              else (0, min(moe.num_experts, 4))),
             )
         mla = self.mla
         if mla.enabled:

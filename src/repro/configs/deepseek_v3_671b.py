@@ -3,8 +3,10 @@
 Source: DeepSeek-V3 [arXiv:2412.19437]. 61 layers (first 3 dense),
 d_model=7168, 128 heads with multi-head latent attention (q_lora 1536,
 kv_lora 512, nope 128 / rope 64 / v 128), routed expert d_ff=2048,
-1 shared + 256 routed experts top-8 with sigmoid routing, vocab 129280,
-one multi-token-prediction (MTP) depth.
+1 shared + 256 routed experts top-8 with sigmoid routing from float32
+router logits whose normalized top-8 weights are scaled by the published
+``routed_scaling_factor`` 2.5, vocab 129280, one multi-token-prediction
+(MTP) depth.
 """
 from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
 
@@ -35,5 +37,7 @@ CONFIG = ModelConfig(
         first_k_dense=3,
         capacity_factor=1.25,
         router_type="sigmoid",     # aux-loss-free bias balancing
+        router_f32=True,           # the published gate's float32 logits
+        routed_scaling_factor=2.5,
     ),
 )

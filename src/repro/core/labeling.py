@@ -55,7 +55,7 @@ the node axis.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Tuple, Union
+from typing import Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +94,10 @@ class SparseHomogenizedSet(NamedTuple):
     weights: jax.Array       # (n, P)
     id_masks: jax.Array      # (n, P)
     thresholds: jax.Array    # (n,)
+    counters: Optional[Dict[str, jax.Array]] = None
+                             # streaming rounds: the models' forward
+                             # counters (``forward_features``), (n,) each,
+                             # summed over both passes
 
     def densify(self, num_classes: int) -> jax.Array:
         """Materialize (n, P[, S], C) labels — diagnostics/tests ONLY;
@@ -222,17 +226,13 @@ def _fused_pass(logits, cfg: IDKDConfig, k: int
     return conf, sparse
 
 
-def _head_pass(model, params_i, x, cfg: IDKDConfig, k: int):
-    """One node's fused head-select pass on one input microbatch.
-
-    ``forward_features`` yields the pre-head activations; the head
-    matrix is applied *inside* the fused select — the ``head_select``
+def _select(model, params_i, feats, cfg: IDKDConfig, k: int):
+    """The fused head-select of one node's pre-head activations: the
+    head matrix is applied *inside* the select — the ``head_select``
     Pallas kernel tiles the vocab axis on TPU, its jnp oracle forms only
     a microbatch-sized logit chunk elsewhere. Returns per-sample
     ``(conf, vals, idx)`` with LM token confidences already reduced to
-    sequence scores (mean over S).
-    """
-    feats, _ = model.forward_features(params_i, {model.input_key: x})
+    sequence scores (mean over S)."""
     w, b = model.head_params(params_i)
     lead = feats.shape[:-1]                                # (mb,) or (mb, S)
     flat = feats.reshape(-1, feats.shape[-1])
@@ -250,9 +250,23 @@ def _head_pass(model, params_i, x, cfg: IDKDConfig, k: int):
     return conf, vals.reshape(lead + (k,)), idx.reshape(lead + (k,))
 
 
+def _head_pass(model, params, x, cfg: IDKDConfig, k: int):
+    """The fused head-select pass of every node on one input microbatch
+    (node-stacked ``params`` and ``x``): the model's
+    ``stacked_features`` yields the pre-head activations (the decoder
+    runs its layer stack on the node-stacked weights in place; a vmap
+    over them has XLA lay every layer stack out anew, a copy of each
+    node's model), then :func:`_select` per node. Returns ``(conf,
+    vals, idx, counters)``, counters those of the forward."""
+    feats, counters = model.stacked_features(params, {model.input_key: x})
+    conf, vals, idx = jax.vmap(
+        lambda p, f: _select(model, p, f, cfg, k))(params, feats)
+    return conf, vals, idx, counters
+
+
 def _vocab_sharded_head_pass(model, params_i, x, cfg: IDKDConfig, k: int,
                              model_axis: str, model_size: int):
-    """:func:`_head_pass` on the 2-D federation mesh (DESIGN.md §10):
+    """One node's head pass on the 2-D federation mesh (DESIGN.md §10):
     each model-axis shard runs the fused select over its own vocab slice
     — ``O(mb · C / model_size)`` scores, never the full row — and the
     per-shard online-softmax stats ``(m, z)`` + top-k raw logits merge
@@ -269,7 +283,7 @@ def _vocab_sharded_head_pass(model, params_i, x, cfg: IDKDConfig, k: int,
     identically. Runs inside ``shard_map`` (under the node-block vmap);
     all collectives are over ``model_axis`` only.
     """
-    feats, _ = model.forward_features(params_i, {model.input_key: x})
+    feats, counters = model.forward_features(params_i, {model.input_key: x})
     w, b = model.head_params(params_i)
     C = w.shape[-1]
     w_sh = -(-C // model_size)
@@ -303,7 +317,8 @@ def _vocab_sharded_head_pass(model, params_i, x, cfg: IDKDConfig, k: int,
     conf = conf.reshape(lead)
     if conf.ndim == 2:                                     # (mb, S) tokens
         conf = conf.mean(-1)
-    return conf, vals.reshape(lead + (k,)), idx.reshape(lead + (k,))
+    return (conf, vals.reshape(lead + (k,)), idx.reshape(lead + (k,)),
+            counters)
 
 
 def _head_width(model, params) -> int:
@@ -367,39 +382,42 @@ def head_reads(model, params, public_x, val_x, cfg: IDKDConfig, *,
 
 def _stream_public(model, params, chunks, P: int, cfg: IDKDConfig, k: int,
                    head_pass=_head_pass):
-    """Scan the chunked public set through the fused head pass for a
-    (possibly local) block of nodes; accumulate only (conf, vals, idx).
-    ``head_pass`` swaps in the vocab-sharded pass on the 2-D mesh. The
-    scan body's operations carry the name scope ``public_pass``.
+    """Scan the chunked public set through the fused head pass of every
+    node of a (possibly local) block at once; accumulate only (conf,
+    vals, idx) and the forward's counters, summed over the chunks ((L,)
+    each). ``head_pass`` swaps in the vocab-sharded pass on the 2-D
+    mesh. The scan body's operations carry the name scope
+    ``public_pass``.
     """
     L = jax.tree.leaves(params)[0].shape[0]
 
     def one_chunk(xc):                                     # (mb, ...)
         xb = jnp.broadcast_to(xc[None], (L,) + xc.shape)
         with jax.named_scope("public_pass"):
-            return jax.vmap(
-                lambda p, x: head_pass(model, p, x, cfg, k))(params, xb)
+            return head_pass(model, params, xb, cfg, k)
 
-    _, (conf, vals, idx) = jax.lax.scan(
+    _, (conf, vals, idx, counters) = jax.lax.scan(
         lambda carry, xc: (carry, one_chunk(xc)), None, chunks)
+    counters = {name: c.sum(0) for name, c in counters.items()}
     total = conf.shape[0] * conf.shape[2]                  # chunks · mb
     conf = jnp.moveaxis(conf, 0, 1).reshape(L, total)[:, :P]
     vals = jnp.moveaxis(vals, 0, 1)
     vals = vals.reshape((L, total) + vals.shape[3:])[:, :P]
     idx = jnp.moveaxis(idx, 0, 1)
     idx = idx.reshape((L, total) + idx.shape[3:])[:, :P]
-    return conf, distill.SparseLabels(vals, idx)
+    return conf, distill.SparseLabels(vals, idx), counters
 
 
 def _stream_val_conf(model, params, val_x, cfg: IDKDConfig,
                      head_pass=_head_pass):
     """Per-node detector confidence on each node's own (small) val set,
-    through the same fused head pass (k=1: only conf is consumed); its
-    operations carry the name scope ``calibration_pass``."""
+    through the same fused head pass (k=1: only conf is consumed), and
+    the forward's counters; its operations carry the name scope
+    ``calibration_pass``."""
+    val_x = jnp.asarray(val_x)
     with jax.named_scope("calibration_pass"):
-        return jax.vmap(
-            lambda p, x: head_pass(model, p, x, cfg, 1)[0])(
-                params, jnp.asarray(val_x))
+        conf, _, _, counters = head_pass(model, params, val_x, cfg, 1)
+    return conf, counters
 
 
 # ------------------------------------------------------------ full round
@@ -514,11 +532,15 @@ def streaming_label_round(model, params, public_x, val_x,
     k = min(cfg.label_topk or DEFAULT_TOPK, C)
     with span("idkd.public_pass"):
         chunks, P, _ = _chunk_public(public_x, cfg.stream_microbatch)
-        conf_pub, sparse = _stream_public(model, params, chunks, P, cfg, k)
+        conf_pub, sparse, counters = _stream_public(model, params, chunks,
+                                                    P, cfg, k)
 
     if filter_ood:
         with span("idkd.calibration_pass"):
-            conf_val = _stream_val_conf(model, params, val_x, cfg)
+            conf_val, val_counters = _stream_val_conf(model, params, val_x,
+                                                      cfg)
+        counters = {name: c + val_counters[name]
+                    for name, c in counters.items()}
     with span("idkd.threshold"):
         if filter_ood:
             thresholds = calibrate(conf_val, conf_pub)
@@ -533,7 +555,8 @@ def streaming_label_round(model, params, public_x, val_x,
         merged, weights = exchange_sparse(topology, id_mask, sparse)
         if active is not None:
             weights = weights * act[:, None]
-    return SparseHomogenizedSet(merged, weights, id_mask, thresholds)
+    return SparseHomogenizedSet(merged, weights, id_mask, thresholds,
+                                counters)
 
 
 # ------------------------------------------------------------ sharded round
@@ -713,9 +736,9 @@ def shard_streaming_label_round(model, params, public_x, val_x,
     spec = P(axis)
     p_specs = federation_specs(params, n, mesh, axis)
     if model_size > 1:
-        def head_pass(model, p, x, cfg, k):
-            return _vocab_sharded_head_pass(model, p, x, cfg, k,
-                                            model_axis, model_size)
+        def head_pass(model, params, x, cfg, k):
+            return jax.vmap(lambda p, x: _vocab_sharded_head_pass(
+                model, p, x, cfg, k, model_axis, model_size))(params, x)
     else:
         head_pass = _head_pass
 
@@ -723,11 +746,14 @@ def shard_streaming_label_round(model, params, public_x, val_x,
         if model_size > 1:
             p_local = gather_model_tree(p_local, p_specs, model_axis)
         # ---- stream / score / calibrate / select: shard-local
-        conf_pub, sp = _stream_public(model, p_local, chunks_rep, P_pub,
-                                      cfg, k, head_pass)
+        # (the forward's counters are not among the sharded round's
+        # outputs)
+        conf_pub, sp, _ = _stream_public(model, p_local, chunks_rep, P_pub,
+                                         cfg, k, head_pass)
         if filter_ood:
             thresholds = calibrate(
-                _stream_val_conf(model, p_local, val_local, cfg, head_pass),
+                _stream_val_conf(model, p_local, val_local, cfg,
+                                 head_pass)[0],
                 conf_pub)
             id_mask = conf_pub > thresholds[:, None]
         else:

@@ -115,7 +115,10 @@ def idkd_label_round(model, params_stacked, public_tokens, private_tokens,
     detector confidences + top-k soft labels on the public corpus,
     ROC-calibrated threshold, sparse neighbour label exchange.
 
-    Returns (sparse_labels, weights (n, P), id_mask, thresholds). The
+    Returns (sparse_labels, weights (n, P), id_mask, thresholds,
+    counters): ``counters`` are the models' forward counters of a
+    node-stacked streaming round ((n,) each, e.g. the held-expert
+    layers' ``held_assignments`` and ``expert_tiles``), else empty. The
     labels stay sparse end to end — neighbour averaging concatenates
     payloads along the k axis (k_out = (max_deg+1)·k) instead of the
     seed's densify→average→resparsify detour through (n, P, S, V).
@@ -150,7 +153,8 @@ def idkd_label_round(model, params_stacked, public_tokens, private_tokens,
             out = labeling.streaming_label_round(
                 model, params_stacked, pub, priv, topology, idkd_cfg,
                 active=active)
-        return out.labels, out.weights, out.id_masks, out.thresholds
+        return (out.labels, out.weights, out.id_masks, out.thresholds,
+                out.counters or {})
 
     n = params_stacked and jax.tree.leaves(params_stacked)[0].shape[0]
 
@@ -173,7 +177,7 @@ def idkd_label_round(model, params_stacked, public_tokens, private_tokens,
         out = labeling.label_round(logits_pub, logits_priv, None,
                                    topology, idkd_cfg, backend=backend,
                                    active=active)
-    return out.labels, out.weights, out.id_masks, out.thresholds
+    return out.labels, out.weights, out.id_masks, out.thresholds, {}
 
 
 class _LMFederation(sched.CompiledFederationHooks):
@@ -253,8 +257,8 @@ class _LMFederation(sched.CompiledFederationHooks):
         """The round's host phases, each an ``idkd.*`` span: ``inputs``
         (the private sequences stacked and uploaded), the streaming
         round's passes, threshold and exchange, ``readback`` (the KD
-        context, the masks and thresholds read back, the label bytes)
-        and ``topk_overlap``."""
+        context, the masks, thresholds and forward counters read back
+        in one transfer, the label bytes) and ``topk_overlap``."""
         cfg = self.idkd_cfg
         n = self.tcfg.num_nodes
         m_priv = max(1, min(16, min(len(p) for p in self.parts)))
@@ -268,15 +272,14 @@ class _LMFederation(sched.CompiledFederationHooks):
             obs_log.warning("idkd.backend_fallback", requested=backend,
                             using="sparse")
             backend = "sparse"
-        sparse, w, id_mask, thr = idkd_label_round(
+        sparse, w, id_mask, thr, counters = idkd_label_round(
             self.model, params, self.public_tokens, priv, cfg, topo,
             backend=backend, active=None if active.all() else active,
             mesh=(self.shard_mesh(n) if self.driver_mode == "shard"
                   else None))
         with span("idkd.readback"):
             self.ctx = driver.lm_kd_ctx(sparse.values, sparse.indices, w)
-            mask = np.asarray(id_mask)
-            thr = np.asarray(thr)
+            mask, thr, counters = jax.device_get((id_mask, thr, counters))
             id_fraction = float(mask.mean())
             counts = mask.sum(axis=1)
             k_wire = min(cfg.label_topk or labeling.DEFAULT_TOPK,
@@ -304,6 +307,10 @@ class _LMFederation(sched.CompiledFederationHooks):
             "thresholds": thr, "selected": counts,
             "id_fraction": id_fraction, "detector": cfg.detector,
             "topk_overlap": mean_ov, "topk_overlap_per_edge": per_edge}
+        # the forward's counters, summed over the nodes (held-expert
+        # layers: held_assignments, expert_tiles)
+        self.last_round_stats.update(
+            {name: int(np.sum(c)) for name, c in counters.items()})
         if _streams(self.model, cfg, backend):
             if self.head_reads is None:
                 self.head_reads = labeling.head_reads(

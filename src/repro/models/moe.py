@@ -10,15 +10,27 @@ all-to-alls at the token→expert buffer boundary.
 Supports: top-k softmax routing (Arctic), sigmoid routing with bias-based
 aux-free balancing (DeepSeek-V3), shared experts, Arctic's dense-residual
 parallel branch, and an optional load-balance auxiliary loss.
+
+Held experts (``MoEConfig.held_experts``): a layer told that it holds
+routed experts ``[first, first + count)`` (a chip's share under expert
+parallelism) keeps its router at the published width, routes every
+token over all ``num_experts``, and computes, without dropping any
+assignment, only the part of the output its own experts give, plus the
+shared experts for every token (``kernels/expert_ffn``). What the absent
+experts would add is left out; their exchange is not modelled. Its
+operations carry the name scopes ``moe_route``, ``moe_dispatch``,
+``expert_ffn`` and ``moe_combine``, and it counts ``held_assignments``
+and ``expert_tiles`` (:func:`moe_layer`).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.expert_ffn import expert_ffn
 from repro.models.layers import apply_mlp, dense_init, init_mlp
 
 
@@ -41,7 +53,7 @@ def init_moe(key, cfg: ModelConfig, dtype):
 
     p = {"router": dense_init(ks[0], d, m.num_experts, dtype, scale=0.02),
          "router_bias": jnp.zeros((m.num_experts,), jnp.float32),
-         "experts": expert_bank(ks[1], m.num_experts, m.moe_d_ff)}
+         "experts": expert_bank(ks[1], m.held_range[1], m.moe_d_ff)}
     if m.num_shared_experts:
         p["shared"] = init_mlp(ks[2], cfg, d, m.num_shared_experts * m.moe_d_ff,
                                dtype)
@@ -51,9 +63,20 @@ def init_moe(key, cfg: ModelConfig, dtype):
 
 
 def _route(params, x, cfg: ModelConfig):
-    """Router: returns (expert_ids (T,k), weights (T,k), aux_loss)."""
+    """Router: returns (expert_ids (T,k), weights (T,k), aux_loss).
+
+    Sigmoid scores (DeepSeek-V3) rank by ``score + router_bias`` and
+    weigh by the unbiased score; the top-k weights are normalized and
+    scaled by ``routed_scaling_factor``. ``router_f32``: the logits
+    from float32 operands at 'highest', as DeepSeek-V3's gate computes
+    them."""
     m = cfg.moe
-    logits = (x @ params["router"]).astype(jnp.float32)       # (T, E)
+    if m.router_f32:
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+    else:
+        logits = (x @ params["router"]).astype(jnp.float32)   # (T, E)
     if m.router_type == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         biased = scores + params["router_bias"]               # bias only ranks
@@ -64,13 +87,14 @@ def _route(params, x, cfg: ModelConfig):
         probs = jax.nn.softmax(logits, axis=-1)
         w, ids = jax.lax.top_k(probs, m.num_experts_per_tok)
         w = w / jnp.maximum(jnp.sum(w, -1, keepdims=True), 1e-9)
+    w = w * m.routed_scaling_factor
     # Switch-style load-balance aux loss (density * mean prob per expert).
     probs = jax.nn.softmax(logits, axis=-1)
     density = jnp.mean(
         jax.nn.one_hot(ids[:, 0], m.num_experts, dtype=jnp.float32), axis=0)
     mean_prob = jnp.mean(probs, axis=0)
     aux = m.num_experts * jnp.sum(density * mean_prob)
-    return ids, w.astype(x.dtype), aux
+    return ids, w, aux
 
 
 def _expert_ffn(bank, xb, cfg: ModelConfig):
@@ -117,7 +141,20 @@ def _dispatch_group(xt, ids, w, bank, cfg: ModelConfig, cap: int):
 
 
 def moe_forward(params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss).
+    """x: (B, S, d) -> (out, aux_loss); :func:`moe_layer` without its
+    counts."""
+    out, aux, _ = moe_layer(params, x, cfg)
+    return out, aux
+
+
+def moe_layer(params, x, cfg: ModelConfig
+              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """x: (B, S, d) -> (out, aux_loss, counts).
+
+    Held experts (``held_experts`` set): dropless, through
+    ``kernels/expert_ffn``; ``counts`` are its ``held_assignments`` and
+    ``expert_tiles`` (int32). Otherwise the capacity path below and no
+    counts.
 
     With ``dispatch_groups == 1`` the argsort spans all tokens (simple but
     unshardable: GSPMD must all-gather every token — see EXPERIMENTS §Perf).
@@ -128,7 +165,16 @@ def moe_forward(params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     B, S, d = x.shape
     T = B * S
     xt = x.reshape(T, d)
-    ids, w, aux = _route(params, xt, cfg)                     # (T,k)
+    with jax.named_scope("moe_route"):
+        ids, w, aux = _route(params, xt, cfg)                 # (T,k)
+    if m.held_experts is not None:
+        base = (apply_mlp(params["shared"], xt, cfg) if m.num_shared_experts
+                else jnp.zeros_like(xt))
+        bank = params["experts"]
+        out, counts = expert_ffn(xt, bank["wg"], bank["wi"], bank["wo"],
+                                 ids - m.held_experts[0], w, base)
+        return out.reshape(B, S, d), aux, counts
+    w = w.astype(x.dtype)
     k = m.num_experts_per_tok
     E = m.num_experts
     G = max(1, m.dispatch_groups)
@@ -148,4 +194,4 @@ def moe_forward(params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
         out = out + apply_mlp(params["shared"], xt, cfg)
     if m.dense_residual_ff:
         out = out + apply_mlp(params["dense_residual"], xt, cfg)
-    return out.reshape(B, S, d), aux
+    return out.reshape(B, S, d), aux, {}

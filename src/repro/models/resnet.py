@@ -97,8 +97,9 @@ class ResNetModel:
         return p
 
     def forward_features(self, params, batch):
-        """batch['images']: (B, H, W, C) float32 -> (feats (B, F), aux=0)
-        — the pooled pre-head activations (streaming labeling hook)."""
+        """batch['images']: (B, H, W, C) float32 -> (feats (B, F),
+        counters={}) — the pooled pre-head activations (streaming
+        labeling hook); the model counts nothing."""
         cfg = self.cfg
         conv = self._conv
         x = batch["images"]
@@ -114,7 +115,12 @@ class ResNetModel:
                 h = evonorm_b0(h, blk["norm2"])
                 sc = conv(x, blk["proj"], stride) if "proj" in blk else x
                 x = jax.nn.relu(h + sc)
-        return jnp.mean(x, axis=(1, 2)), jnp.zeros((), jnp.float32)
+        return jnp.mean(x, axis=(1, 2)), {}
+
+    def stacked_features(self, params, batch):
+        """:meth:`forward_features` of node-stacked ``params`` and
+        ``batch``, vmapped over the nodes."""
+        return jax.vmap(self.forward_features)(params, batch)
 
     def head_params(self, params):
         """(weight (F, C), bias (C,)) of the classifier head."""
@@ -122,9 +128,9 @@ class ResNetModel:
 
     def forward(self, params, batch):
         """batch['images']: (B, H, W, C) float32 -> (logits, aux=0)."""
-        x, aux = self.forward_features(params, batch)
+        x, _ = self.forward_features(params, batch)
         w, b = self.head_params(params)
-        return x @ w + b, aux
+        return x @ w + b, jnp.zeros((), jnp.float32)
 
     def loss(self, params, batch):
         logits, aux = self.forward(params, batch)

@@ -28,7 +28,7 @@ from repro.models import attention as attn
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (apply_mlp, apply_norm, dense_init,
                                  embed_init, init_mlp, init_norm)
-from repro.models.moe import init_moe, moe_forward
+from repro.models.moe import init_moe, moe_forward, moe_layer
 
 
 def _dtype(cfg: ModelConfig):
@@ -111,25 +111,29 @@ def _mix_forward(p, h, cfg: ModelConfig, window, memory):
     if cfg.ssm.enabled:
         return ssm_mod.ssm_forward(p["ssm"], h, cfg)
     if cfg.mla.enabled:
-        return attn.mla_forward(p["attn"], h, cfg)
+        with jax.named_scope("mla"):
+            return attn.mla_forward(p["attn"], h, cfg)
     return attn.attention_forward(p["attn"], h, cfg, layer_window=window)
 
 
 def _layer_forward(p, x, cfg: ModelConfig, kind: str, window, memory):
+    """One layer: (x, aux loss, counts); counts are the held-expert
+    layer's (``moe.moe_layer``), empty for every other layer."""
     h = apply_norm(p["ln1"], x, cfg)
     x = x + _mix_forward(p, h, cfg, window, memory)
     aux = jnp.zeros((), jnp.float32)
+    counts = {}
     if cfg.cross_attention and memory is not None:
         h = apply_norm(p["ln_cross"], x, cfg)
         x = x + attn.cross_attention_forward(p["cross"], h, memory, cfg)
     if "ln2" in p:
         h = apply_norm(p["ln2"], x, cfg)
         if kind == "moe":
-            y, aux = moe_forward(p["moe"], h, cfg)
+            y, aux, counts = moe_layer(p["moe"], h, cfg)
         else:
             y = apply_mlp(p["mlp"], h, cfg)
         x = x + y
-    return x, aux
+    return x, aux, counts
 
 
 def _mix_decode(p, h, cfg: ModelConfig, window, layer_state):
@@ -278,64 +282,126 @@ class DecoderModel:
         return h @ params["head"]
 
     # -- forward ----------------------------------------------------------------
-    def _run_stack(self, params, h, memory):
+    def _run_stack(self, params, h, memory, nodes: int = 0):
+        """(h, aux loss, counts summed over the layers).
+
+        ``nodes > 0``: ``params``, ``h`` and ``memory`` carry a leading
+        axis of that many nodes, the aux loss and counts come back per
+        node, and each layer reads each node's weights by one dynamic
+        slice of the node-stacked arrays. (Under a ``vmap`` over nodes
+        the scan's per-layer read is a slice across the nodes, for which
+        XLA lays every stacked weight out anew: a copy of the model.)"""
         cfg = self.cfg
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total = jnp.zeros((nodes,) if nodes else (), jnp.float32)
+        counts = {}
         seg_windows = self._segment_windows()
         for si, seg in enumerate(self.segments):
             stacked = params[f"layers_{si}"]
             windows = seg_windows[si]
 
-            def body(x, scanned, kind=seg.kind):
-                lp, win = scanned
-
-                def f(lp_, x_, win_):
-                    return _layer_forward(lp_, x_, cfg, kind, win_, memory)
+            def layer(lp, x, win, mem, kind=seg.kind):
+                def f(lp_, x_, win_, mem_):
+                    return _layer_forward(lp_, x_, cfg, kind, win_, mem_)
                 if cfg.remat:
                     f = jax.checkpoint(f, policy=_remat_policy(cfg))
-                y, aux = f(lp, x, win)
-                return _constrain(y), aux
+                if nodes:
+                    f = jax.vmap(f, in_axes=(0, 0, None,
+                                             None if mem is None else 0))
+                return f(lp, x, win, mem)
+
+            def weights(li, stacked=stacked):
+                if not nodes:
+                    return jax.tree.map(lambda t: t[li], stacked)
+                return jax.tree.map(lambda t: jnp.stack([
+                    jax.lax.dynamic_slice(
+                        t, (i, li) + (0,) * (t.ndim - 2),
+                        (1, 1) + t.shape[2:]).reshape(t.shape[2:])
+                    for i in range(nodes)]), stacked)
+
+            def body(x, scanned, layer=layer):
+                lp, win = scanned
+                y, aux, c = layer(lp, x, win, memory)
+                return _constrain(y), (aux, c)
 
             if cfg.scan_layers and seg.num_layers > 1:
-                h, auxs = jax.lax.scan(body, h, (stacked, windows))
-                aux_total = aux_total + jnp.sum(auxs)
+                if nodes:
+                    h, (auxs, cs) = jax.lax.scan(
+                        lambda x, li, body=body, weights=weights,
+                        windows=windows: body(x, (weights(li), windows[li])),
+                        h, jnp.arange(seg.num_layers))
+                else:
+                    h, (auxs, cs) = jax.lax.scan(body, h, (stacked, windows))
+                aux_total = aux_total + jnp.sum(auxs, 0)
+                cs = {k: jnp.sum(v, 0) for k, v in cs.items()}
             else:
+                cs = {}
                 for li in range(seg.num_layers):
-                    lp = jax.tree.map(lambda t: t[li], stacked)
-                    h, aux = body(h, (lp, windows[li]))
+                    h, (aux, c) = body(h, (weights(li), windows[li]))
                     aux_total = aux_total + aux
-        return h, aux_total
+                    cs = {k: cs.get(k, 0) + v for k, v in c.items()}
+            counts = {k: counts.get(k, 0) + v for k, v in cs.items()}
+        return h, aux_total, counts
 
-    def hidden(self, params, batch: Dict[str, Any]):
-        """Post-stack, post-final-norm hidden states with prefixes stripped.
-        Returns (h, aux)."""
+    def _inputs(self, params, batch: Dict[str, Any]):
+        """(embedded inputs with any prefix, conditioning memory)."""
         cfg = self.cfg
-        tokens = batch["tokens"]
-        h = self.embed_tokens(params, tokens)
+        h = self.embed_tokens(params, batch["tokens"])
         B = h.shape[0]
-        n_prefix = 0
         if cfg.arch_type == "hybrid" and cfg.num_prefix_tokens:
             meta = jnp.broadcast_to(params["meta_tokens"][None],
                                     (B,) + params["meta_tokens"].shape)
             h = jnp.concatenate([meta, h], axis=1)
-            n_prefix = cfg.num_prefix_tokens
         if cfg.arch_type == "vlm":
             patches = batch["patch_embeddings"].astype(h.dtype)  # (B,P,d)
             h = jnp.concatenate([patches, h], axis=1)
-            n_prefix = patches.shape[1]
         memory = batch.get("conditioning") if cfg.cross_attention else None
         if memory is not None:
             memory = memory.astype(h.dtype)
-        h, aux = self._run_stack(params, h, memory)
-        h = apply_norm(params["ln_f"], h, cfg)
-        if n_prefix:
-            h = h[:, n_prefix:]
+        return h, memory
+
+    def _prefix_len(self, batch: Dict[str, Any]) -> int:
+        cfg = self.cfg
+        if cfg.arch_type == "hybrid" and cfg.num_prefix_tokens:
+            return cfg.num_prefix_tokens
+        if cfg.arch_type == "vlm":
+            return batch["patch_embeddings"].shape[-2]
+        return 0
+
+    def _final(self, params, h, n_prefix: int):
+        h = apply_norm(params["ln_f"], h, self.cfg)
+        return h[..., n_prefix:, :] if n_prefix else h
+
+    def _hidden(self, params, batch: Dict[str, Any]):
+        h, memory = self._inputs(params, batch)
+        h, aux, counts = self._run_stack(params, h, memory)
+        return self._final(params, h, self._prefix_len(batch)), aux, counts
+
+    def hidden(self, params, batch: Dict[str, Any]):
+        """Post-stack, post-final-norm hidden states with prefixes stripped.
+        Returns (h, aux)."""
+        h, aux, _ = self._hidden(params, batch)
         return h, aux
 
+    def stacked_features(self, params, batch: Dict[str, Any]):
+        """:meth:`forward_features` of node-stacked ``params`` and
+        ``batch`` (a leading node axis on every leaf), counters per
+        node; the layer stack reads each node's weights in place
+        (:meth:`_run_stack`)."""
+        nodes = jax.tree.leaves(params)[0].shape[0]
+        h, memory = jax.vmap(self._inputs)(params, batch)
+        h, _, counts = self._run_stack(params, h, memory, nodes=nodes)
+        n_prefix = self._prefix_len(jax.tree.map(lambda t: t[0], batch))
+        return jax.vmap(lambda p, x: self._final(p, x, n_prefix))(
+            params, h), counts
+
     def forward_features(self, params, batch: Dict[str, Any]):
-        """Pre-head activations (B, S, d) — alias of :meth:`hidden`, the
-        streaming-labeling hook shared with ResNetModel."""
-        return self.hidden(params, batch)
+        """(pre-head activations (B, S, d), counters): the
+        streaming-labeling hook shared with ResNetModel. The counters are
+        the held-expert layers' int32 counts summed over the layers
+        (``held_assignments``, ``expert_tiles``), empty for a model
+        without such layers."""
+        h, _, counts = self._hidden(params, batch)
+        return h, counts
 
     def head_params(self, params):
         """(unembedding (d, V), bias=None) — the matrix the streaming
@@ -390,7 +456,7 @@ class DecoderModel:
         emb_next = self.embed_tokens(params, jnp.roll(tokens, -1, axis=1))
         hcat = jnp.concatenate([h, emb_next], axis=-1) @ params["mtp_proj"]
         win = jnp.asarray(0, jnp.int32)
-        hcat, _ = _layer_forward(params["mtp_layer"], hcat, cfg,
+        hcat, _, _ = _layer_forward(params["mtp_layer"], hcat, cfg,
                                  self.segments[-1].kind, win, None)
         logits = self.logits(params, hcat)
         labels = jnp.roll(batch["labels"], -1, axis=1)

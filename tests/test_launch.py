@@ -13,7 +13,7 @@ from repro.models import build_model
 
 
 def test_registry_complete():
-    assert len(ASSIGNED_ARCHS) == 10
+    assert len(ASSIGNED_ARCHS) == 11
     assert set(SHAPES) == {"train_4k", "prefill_32k", "decode_32k",
                            "long_500k"}
     for a in ASSIGNED_ARCHS:

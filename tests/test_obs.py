@@ -353,11 +353,11 @@ def test_lm_trajectory_invariant_under_telemetry(tmp_path):
     assert parents["idkd.public_pass"] == "idkd.round"
 
 
-def _tiny_lm_federation():
+def _tiny_lm_federation(cfg=None):
     """The LM hooks at a tiny size: 2 nodes on a ring, 4 private and 4
-    public sequences of 8 tokens, a 1-layer f32 model with d_model 16
-    and a 32-token vocabulary. Returns (hooks, params, topology,
-    active)."""
+    public sequences of 8 tokens, by default a 1-layer f32 model with
+    d_model 16 and a 32-token vocabulary (``cfg`` replaces it). Returns
+    (hooks, params, topology, active)."""
     from repro.configs import get_config
     from repro.core.algorithms import make_algorithm
     from repro.core.topology import Topology
@@ -365,10 +365,12 @@ def _tiny_lm_federation():
     from repro.launch.train import _LMFederation
     from repro.models import build_model
 
-    n, M, S, V = 2, 4, 8, 32
-    cfg = get_config("qwen1.5-0.5b").reduced().replace(
-        num_layers=1, d_model=16, num_heads=2, num_kv_heads=2, head_dim=8,
-        d_ff=32, vocab_size=V, dtype="float32")
+    n, M, S = 2, 4, 8
+    if cfg is None:
+        cfg = get_config("qwen1.5-0.5b").reduced().replace(
+            num_layers=1, d_model=16, num_heads=2, num_kv_heads=2,
+            head_dim=8, d_ff=32, vocab_size=32, dtype="float32")
+    V = cfg.vocab_size
     model = build_model(cfg)
     idkd = IDKDConfig(label_topk=4, label_backend="sparse")
     tcfg = TrainConfig(num_nodes=n, steps=1, batch_size=2, idkd=idkd)
@@ -436,6 +438,86 @@ def test_lm_round_counts_head_reads_from_shapes(monkeypatch):
     want = _tiny_head_reads(n=2, rows_pub=4 * 8, rows_cal=4 * 8, d=16,
                             vocab=32)
     assert fed.last_round_stats["head_reads"] == want == 2 * (4 + 4)
+
+
+def _tiny_moe_config():
+    """Moonlight's layers at a tiny size: d 128, 1 dense + 2 expert
+    layers of 8 experts top-2, 2 of them held, vocab 64, float32."""
+    import dataclasses
+
+    from repro.configs import get_config
+    cfg = get_config("moonlight-16b-a3b").replace(
+        num_layers=3, d_model=128, num_heads=2, num_kv_heads=2, d_ff=64,
+        vocab_size=64, dtype="float32")
+    return cfg.replace(
+        mla=dataclasses.replace(cfg.mla, kv_lora_rank=16,
+                                qk_nope_head_dim=8, qk_rope_head_dim=8,
+                                v_head_dim=8),
+        moe=dataclasses.replace(cfg.moe, num_experts=8,
+                                num_experts_per_tok=2, moe_d_ff=32,
+                                held_experts=(2, 2)))
+
+
+def test_lm_round_counts_held_expert_work(tmp_path):
+    """A round of a held-expert model: ``held_assignments`` equals the
+    held picks counted on the host from each layer's router, summed over
+    nodes, expert layers and both passes; ``expert_tiles`` the live
+    kernel tiles of the same picks; the scheduler's ``labels`` event
+    carries both."""
+    import jax.numpy as jnp
+
+    from repro import sched
+    from repro.kernels.expert_ffn import row_tile
+    from repro.models import attention as attn
+    from repro.models.layers import apply_norm
+    from repro.models.moe import _route
+    from repro.models.transformer import _layer_forward
+
+    cfg = _tiny_moe_config()
+    fed, params, topo, active = _tiny_lm_federation(cfg)
+    model = fed.model
+    m_priv = 4
+    priv = np.stack([fed.tokens[fed.parts[i][:m_priv], :fed.seq_len]
+                     for i in range(topo.n)])
+    held = tiles = 0
+    first, count = cfg.moe.held_experts
+    for i in range(topo.n):
+        p = jax.tree.map(lambda t: t[i], params)
+        for toks in (fed.public_tokens, priv[i]):
+            x = model.embed_tokens(p, jnp.asarray(toks))
+            lp = jax.tree.map(lambda t: t[0], p["layers_0"])
+            x = _layer_forward(lp, x, cfg, "dense", 0, None)[0]
+            tm = row_tile(-(-x.shape[0] * x.shape[1] * 2 // count))
+            for li in range(2):
+                lp = jax.tree.map(lambda t: t[li], p["layers_1"])
+                h = x + attn.mla_forward(
+                    lp["attn"], apply_norm(lp["ln1"], x, cfg), cfg)
+                ids = _route(lp["moe"], apply_norm(
+                    lp["ln2"], h, cfg).reshape(-1, 128), cfg)[0]
+                local = np.asarray(ids) - first
+                on = (local >= 0) & (local < count)
+                held += int(on.sum())
+                sizes = np.bincount(local[on], minlength=count)
+                tiles += int(np.sum(-(-sizes // tm)))
+                x = _layer_forward(lp, x, cfg, "moe", 0, None)[0]
+    # the round through the scheduler; the one training step after it
+    # is stubbed out (the held-expert kernel has no gradient)
+    def runner(*_):
+        def run(params, opt_state, key, start, steps, **_):
+            return params, opt_state, key, jnp.zeros((steps, topo.n))
+        return run
+
+    fed.runner = runner
+    tel = Telemetry(tmp_path)
+    schedule = sched.compile_schedule(1, 1, round_steps=(0,))
+    sched.run_schedule(schedule, fed, params, None, jax.random.PRNGKey(0),
+                       topology=topo, telemetry=tel)
+    tel.close()
+    stats = fed.last_round_stats
+    assert stats["held_assignments"] == held > 0
+    assert stats["expert_tiles"] == tiles
+    lab = read_events(tmp_path / "run.jsonl", "labels")[0]
+    assert (lab["held_assignments"], lab["expert_tiles"]) == (held, tiles)
 
 
 # --------------------------------------------- acceptance scenario

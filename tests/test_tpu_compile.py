@@ -11,6 +11,7 @@ The topology is described only inside the module fixture: describing it
 loads the TPU library, which one process at a time may hold, so nothing
 here touches it at import.
 """
+import dataclasses
 import os
 
 import jax
@@ -103,4 +104,49 @@ def test_msp_select_compiles_at_qwen3_vocab(one_chip):
     text = _compiled_text(
         lambda x: msp_select(x, temperature=10.0, k=8, interpret=False),
         logits)
+    assert "tpu_custom_call" in text
+
+
+MOON_D, MOON_F, MOON_HELD, MOON_K = 2048, 1408, 8, 6   # moonlight-16b-a3b
+
+
+def test_expert_ffn_compiles_at_moonlight_cell_shapes(one_chip):
+    """The held-expert kernel as the moonlight-16b-a3b label round runs
+    it: 8 held experts of 2048 x 1408, the worst-case row count of a
+    256-sequence microbatch (32,768 tokens x top-6), two nodes under
+    ``vmap`` (the node axis becomes the kernel's leading grid axis)."""
+    from repro.kernels.expert_ffn import expert_ffn
+    T = 256 * 128
+    args = [_spec((2, T, MOON_D), jnp.bfloat16, one_chip),
+            _spec((2, MOON_HELD, MOON_D, MOON_F), jnp.bfloat16, one_chip),
+            _spec((2, MOON_HELD, MOON_D, MOON_F), jnp.bfloat16, one_chip),
+            _spec((2, MOON_HELD, MOON_F, MOON_D), jnp.bfloat16, one_chip),
+            _spec((2, T, MOON_K), jnp.int32, one_chip),
+            _spec((2, T, MOON_K), jnp.float32, one_chip),
+            _spec((2, T, MOON_D), jnp.bfloat16, one_chip)]
+    text = _compiled_text(jax.vmap(
+        lambda *a: expert_ffn(*a, interpret=False)), *args)
+    assert "tpu_custom_call" in text
+
+
+def test_moe_decoder_layer_compiles_at_moonlight_widths(one_chip,
+                                                        monkeypatch):
+    """One expert layer of moonlight-16b-a3b at its published widths
+    (latent attention without q-LoRA, the router over 64 experts, 8 held
+    experts, 2 shared) over 8 sequences of 128 tokens, its expert part
+    on the Mosaic kernel (the layer takes the interpreter wherever the
+    default backend is not a TPU, as it is here)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from repro.configs import get_config
+    from repro.models.transformer import _init_layer, _layer_forward
+    cfg = get_config("moonlight-16b-a3b")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
+                                              held_experts=(0, MOON_HELD)))
+    shapes = jax.eval_shape(
+        lambda k: _init_layer(k, cfg, "moe", jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    lp = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), shapes)
+    x = _spec((8, 128, MOON_D), jnp.bfloat16, one_chip)
+    text = _compiled_text(
+        lambda lp, x: _layer_forward(lp, x, cfg, "moe", 0, None), lp, x)
     assert "tpu_custom_call" in text
